@@ -23,9 +23,10 @@ endpoints + result cache (each endpoint with a bounded admission queue)
 cache) while a writer churns the live endpoint -> report per-endpoint
 latency percentiles, batch fill, overload counters, execution backend +
 corpus dtype, cache hit-rate, and MRR@10 on the sparse funnel — and
-verify that the sharded reference-backed fused endpoint answered
-bit-identically to the kernel-backed one, the pallas dense endpoint
-bit-identically to the reference one, the bf16 dense endpoint
+verify that the sharded reference-backed fused endpoint answered as the
+kernel-backed one does, the pallas dense endpoint as the reference one
+does (same ids, scores within the f32 bound of
+``repro.core.precision``), the bf16 dense endpoint
 recall-identically (the bounded-error precision tier) to the f32 one,
 the graph-ANN endpoint to recall@10 >= the declared target (the
 measured-recall tier) vs the exact one, and — after the churn drains
@@ -49,6 +50,7 @@ from repro.core.backends import ANN_RECALL_TARGET, GraphANNBackend
 from repro.core.fusion import (coordinate_ascent, learn_fused_weights, mrr,
                                topk_recall)
 from repro.core.inverted_index import daat_topk
+from repro.core.precision import topk_close_violations
 from repro.core.pipeline import (BruteForceGenerator, LinearReranker,
                                  RetrievalPipeline)
 from repro.core.scorers import (CompositeExtractor, bm25_doc_vectors,
@@ -60,6 +62,7 @@ from repro.core import segments
 from repro.data.pipeline import pad_tokens
 from repro.data.synthetic import make_corpus, qrels_to_labels
 from repro.distributed.sharding import ParallelCtx
+from repro.kernels.platform import interpreted
 from repro.models import transformer as T
 from repro.models.encoder import CrossEncoderReranker
 from repro.serving import (EndpointSpec, FunnelPipeline, RetrievalService,
@@ -149,7 +152,7 @@ def build_service(rc, corpus):
 
     # the same corpus and funnel through the Pallas fused MIPS+top-k
     # kernel (interpret mode off-TPU): one registration kwarg is the whole
-    # difference, and the answers are bit-identical to "dense"
+    # difference, and the answers match "dense" within the f32 bound
     svc.register_pipeline("dense_pallas", dense_pipe, q_dense_all[0],
                           batch_size=16, max_wait_s=0.01,
                           backend="pallas")
@@ -170,9 +173,11 @@ def build_service(rc, corpus):
     # (including kernel=on) lands in snapshots and cache keys, and
     # main() measures recall vs the exact "dense" sibling live.
     # kernel=True traverses the graph through the fused Pallas beam
-    # kernel (kernels/beam_topk.py; interpret mode off-TPU) — same
-    # contract, sub-linear per-hop work at corpus scale
-    ann_backend = GraphANNBackend(ef=max(64, rc.cand_qty), kernel=True)
+    # kernel (kernels/beam_topk.py) — same contract, sub-linear per-hop
+    # work at corpus scale.  Mosaic does not compile it yet, so on a TPU
+    # the jnp hop loop serves.
+    beam_kernel = interpreted()
+    ann_backend = GraphANNBackend(ef=max(64, rc.cand_qty), kernel=beam_kernel)
     svc.register_pipeline("dense_ann", dense_pipe, q_dense_all[0],
                           batch_size=16, max_wait_s=0.01,
                           backend=ann_backend)
@@ -212,13 +217,13 @@ def build_service(rc, corpus):
         "dense_funnel", funnel, q_dense_all[0], q_tokens_all[0],
         spec=EndpointSpec(batch_size=16, max_wait_s=0.01,
                           backend=GraphANNBackend(ef=max(64, rc.cand_qty),
-                                                  kernel=True),
+                                                  kernel=beam_kernel),
                           budget=StageBudget(rerank_s=2.0)))
 
     # the mixed representation with the LEARNED mixing weights, scored and
     # selected on-device by the fused Pallas kernel (interpret mode
     # off-TPU): backend="pallas" is the whole difference, and the answers
-    # stay bit-identical to the reference-backed sharded endpoint below
+    # match the reference-backed sharded endpoint below (f32 rule)
     fused_space = FusedSpace(v, w_dense=w_dense, w_sparse=w_sparse)
     fused_corpus = FusedVectors(doc_dense, doc_bm25)
     fused_pipe = RetrievalPipeline(
@@ -231,8 +236,8 @@ def build_service(rc, corpus):
 
     # the same fused space served from a 2-way sharded corpus on the
     # reference backend: one endpoint, per-shard search + global merge,
-    # bit-identical to the kernel-backed "fused" (cross-backend AND
-    # cross-layout identity); the bounded queue with "block" backpressures
+    # the same answers as the kernel-backed "fused" (cross-backend AND
+    # cross-layout, under the f32 rule); the bounded queue with "block" backpressures
     # clients instead of dropping work (benchmarks/serve_bench.py
     # exercises the reject/shed policies)
     fused_sharded = ShardedPipeline.from_corpus(
@@ -338,8 +343,9 @@ def main():
         snap = svc.snapshot()
 
         # sharded-vs-unsharded and pallas-vs-reference spot checks: same
-        # queries through both members of each pair must come back
-        # bit-identical
+        # queries through both members of each pair must come back with
+        # the same ids and scores within the f32 bound (a compiled kernel
+        # and XLA's dot sum in different orders, so not bit for bit)
         check = [int(q) for q in query_pool[:8]]
         for ep_a, ep_b in (("fused", "fused_sharded"),
                            ("dense", "dense_pallas")):
@@ -348,9 +354,8 @@ def main():
             futs_b = [svc.submit(*reprs[ep_b](i), endpoint=ep_b)
                       for i in check]
             for a, b in zip(futs_a, futs_b):
-                ra, rb = a.result(), b.result()
-                assert np.array_equal(ra.scores, rb.scores), (ep_a, ep_b)
-                assert np.array_equal(ra.indices, rb.indices), (ep_a, ep_b)
+                bad = topk_close_violations(a.result(), b.result())
+                assert not bad, (ep_a, ep_b, bad)
 
         # bf16-vs-f32 spot check: the bounded-error precision tier can't
         # be bitwise, so the contract is recall parity — the bf16
@@ -468,8 +473,9 @@ def main():
                       f"occupancy {ep.stage_occupancy[st]:.0%}  "
                       f"fallbacks {ep.stage_fallbacks[st]}  "
                       f"overruns {ep.stage_overruns[st]}")
-    print("fused_sharded bit-identical to fused, dense_pallas "
-          "bit-identical to dense, dense_bf16 recall@10 == 1.0 vs dense, "
+    print("fused_sharded matches fused, dense_pallas matches dense (same "
+          "ids, scores within the f32 bound), dense_bf16 recall@10 == 1.0 "
+          "vs dense, "
           "dense_ann recall@10 >= target vs dense, dense_live recall@10 "
           "== 1.0 vs the exact frozen oracle after churn + compaction, "
           "on spot-check queries")
@@ -491,4 +497,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -20,19 +20,19 @@ with three registered implementations:
     for dense ip/l2 f32/bf16 corpora, ``kernels.fused_topk`` for
     fused/sparse ip f32/bf16 corpora (the paper's mixed dense+sparse
     representation scored AND selected on-device in one pass, learned
-    mixing weights baked into the launch).  Interpret mode off-TPU
-    (same arithmetic, CPU speed); ``tile_n=None`` auto-tunes the tile
+    mixing weights baked into the launch).  Compiled by Mosaic on a
+    TPU, interpret mode elsewhere; ``tile_n=None`` auto-tunes the tile
     from the roofline cost model through a thread-safe warm cache
     keyed per (space kind, corpus shape, dtype) configuration.
 
 All three produce **f32 scores** regardless of corpus residency dtype
-(the precision contract in ``core.spaces``), and are **bit-identical to
-each other per corpus dtype**: the kernels' per-element arithmetic
-orders — including the per-tile bf16→f32 upcasts — match
-``spaces.dense_scores`` exactly, and every selection path breaks score
-ties toward the lower corpus row id (``tests/test_backends.py`` sweeps
-f32; ``tests/test_bf16.py`` sweeps bf16 plus its vs-f32-oracle recall
-and ULP-error bounds).
+(the precision contract in ``core.spaces``), and **agree with each
+other per corpus dtype under the f32 rule of ``core.precision``** (same
+ids, scores within a stated bound: a compiled kernel and XLA's dot sum
+in different orders), and every selection path breaks score ties
+toward the lower corpus row id (``tests/test_backends.py`` sweeps f32;
+``tests/test_bf16.py`` sweeps bf16 plus its vs-f32-oracle recall and
+ULP-error bounds).
 
 Two further backends are **approximate** — the paper's actual headline
 (NMSLIB's SW-graph and NAPP as pluggable methods over arbitrary
@@ -175,17 +175,21 @@ def clear_tile_cache():
 
 
 def auto_tile_n(n_rows: int, *, b: int, k: int, bytes_per_row: float,
-                flops_per_row: float, resident_bytes: float = 0.0) -> int:
+                flops_per_row: float, resident_bytes: float = 0.0,
+                scratch_per_row: float = 0.0) -> int:
     """Roofline-driven ``tile_n``: the legal tile minimising estimated
     seconds *per corpus row* (``launch.roofline.topk_tile_seconds``)
-    among power-of-two lane multiples whose VMEM working set fits.
+    among power-of-two lane multiples whose VMEM working set fits half
+    the device's scoped VMEM (``launch.roofline.device_peaks``).
 
     The working set per grid step is the resident operands
-    (``resident_bytes``: queries, the densified query table, the running
-    top-k) plus the streamed corpus tile double-buffered plus the
-    ``[B, tile]`` f32 score block.  Small tiles re-pay the ``B*K^2`` fold
-    term too often; large tiles blow the VMEM budget — the cost model
-    picks the knee instead of a fixed 1024/2048.
+    (``resident_bytes``: queries, the query terms, the running top-k)
+    plus the streamed corpus tile double-buffered plus the ``[B, tile]``
+    f32 score block plus per-row kernel scratch (``scratch_per_row``:
+    the fused kernel's transposed COO tile and term hits).  Small tiles
+    re-pay the ``B*K^2`` fold term too often; large tiles blow the VMEM
+    budget — the cost model picks the knee instead of a fixed 1024/2048.
+    ``tests/test_tpu_compile.py`` compiles the picked tiles for a v5e.
 
     Results are memoised in a thread-safe warm cache keyed on the full
     argument tuple, so repeated calls over the same (space kind, corpus
@@ -194,7 +198,8 @@ def auto_tile_n(n_rows: int, *, b: int, k: int, bytes_per_row: float,
     (:func:`tile_cache_info` / :func:`clear_tile_cache`)."""
     global _TILE_CACHE_HITS, _TILE_CACHE_MISSES
     key = (int(n_rows), int(b), int(k), float(bytes_per_row),
-           float(flops_per_row), float(resident_bytes))
+           float(flops_per_row), float(resident_bytes),
+           float(scratch_per_row))
     with _TILE_CACHE_LOCK:
         cached = _TILE_CACHE.get(key)
         if cached is not None:
@@ -202,13 +207,15 @@ def auto_tile_n(n_rows: int, *, b: int, k: int, bytes_per_row: float,
             return cached
         # the sweep is a handful of closed-form evaluations — cheap
         # enough to run under the lock, which keeps the counters exact
-        from repro.launch.roofline import VMEM_BYTES, topk_tile_seconds
+        from repro.launch.roofline import device_peaks, topk_tile_seconds
 
-        budget = VMEM_BYTES // 2      # leave headroom for compiler temps
+        # leave half for compiler temporaries
+        budget = device_peaks().scoped_vmem_bytes // 2
         best, best_cost = 128, None
         tile = 128                    # lane-dim multiple (f32 MXU face)
         while tile <= 16384:
-            fits = (resident_bytes + tile * (2 * bytes_per_row + 4 * b)
+            fits = (resident_bytes + tile * (2 * bytes_per_row + 4 * b
+                                             + scratch_per_row)
                     <= budget)
             if fits:
                 cost = topk_tile_seconds(
@@ -343,29 +350,25 @@ class PallasBackend:
 
     ``tile_n=None`` (the default) auto-tunes the corpus tile per call
     from the roofline cost model (:func:`auto_tile_n`) instead of a
-    fixed size — tiles are legal by construction and results are
-    bit-identical at any tile, so tuning never changes answers.
+    fixed size — tiles are legal by construction and answers agree at
+    any tile under the f32 rule of ``core.precision``, so tuning never
+    changes the ids served.
 
-    ``interpret=None`` resolves per platform: compiled on TPU,
-    interpret mode elsewhere (identical arithmetic, CPU speed — the
-    parity tests and CI run exactly this path)."""
+    The kernels compile with Mosaic on a TPU and run in interpret mode
+    elsewhere (``kernels.platform``, decided when the program is
+    lowered); the identity names the mode the default device gets."""
 
     tile_n: Optional[int] = None
-    interpret: Optional[bool] = None
     name = "pallas"
 
     _DTYPES = ("float32", "bfloat16")
 
     @property
     def identity(self) -> str:
-        interp = "auto" if self.interpret is None else self.interpret
-        tile = "auto" if self.tile_n is None else self.tile_n
-        return f"pallas(tile_n={tile},interpret={interp})"
+        from repro.kernels.platform import interpreted
 
-    def _interpret(self) -> bool:
-        if self.interpret is not None:
-            return self.interpret
-        return jax.default_backend() != "tpu"
+        tile = "auto" if self.tile_n is None else self.tile_n
+        return f"pallas(tile_n={tile},interpret={interpreted()})"
 
     def supports(self, space, corpus) -> Optional[str]:
         if isinstance(space, DenseSpace):
@@ -393,10 +396,8 @@ class PallasBackend:
             if corpus.dense is None and corpus.sparse is None:
                 return "fused corpus has no components"
             if corpus.dense is not None:
-                # ip only: the l2 corpus-norm term constant-folds with
-                # different bits than the kernel computes at runtime when
-                # a jitted funnel closes over the corpus, so the
-                # bit-identity contract cannot be kept for fused l2
+                # ip only: the fused space's l2 dense kind has no
+                # served or parity tests on the kernel path
                 if space.dense_kind != "ip":
                     return ("pallas fused kernel serves dense_kind 'ip', "
                             f"not {space.dense_kind!r}")
@@ -420,11 +421,12 @@ class PallasBackend:
                            flops_per_row=2 * b * d,
                            resident_bytes=b * (d + 2 * k) * 4)
 
-    def _fused_tile(self, n: int, b: int, k: int, vocab: int,
+    def _fused_tile(self, n: int, b: int, k: int, q_terms: int,
                     nnz: int, dd: int, val_itemsize: int = 4,
                     dense_itemsize: int = 4) -> int:
         if self.tile_n is not None:
             return legal_tile(n, self.tile_n)
+        bt = b * q_terms
         return auto_tile_n(
             n, b=b, k=k,
             # COO stream is i32 ids + storage-dtype values; the dense
@@ -432,8 +434,10 @@ class PallasBackend:
             # both value streams, so the roofline re-tunes (through its
             # own warm-cache key) toward larger tiles
             bytes_per_row=nnz * (4 + val_itemsize) + dd * dense_itemsize,
-            flops_per_row=2 * b * (nnz + dd),
-            resident_bytes=b * (vocab + 1 + dd + 2 * k) * 4)
+            flops_per_row=2 * b * dd + 3 * bt * nnz + 2 * b * bt,
+            resident_bytes=(bt * (b + 1) + b * (dd + 2 * k)) * 4,
+            # the fused kernel's transposed COO tile and [B*T] term hits
+            scratch_per_row=(8 * nnz + 4 * bt) if nnz else 0)
 
     def topk(self, space, query_repr, corpus, k: int,
              n_valid: Optional[int] = None) -> TopK:
@@ -447,8 +451,7 @@ class PallasBackend:
             head = (ops.mips_topk(
                         query_repr, corpus, k_eff,
                         tile_n=self._dense_tile(n, b, k_eff, corpus),
-                        space=space.kind, interpret=self._interpret(),
-                        n_valid=n_valid)
+                        space=space.kind, n_valid=n_valid)
                     if k_eff else _empty_topk(b))
             return (head if k_eff == k
                     else _reference_tail(head, b, k, n_valid))
@@ -473,9 +476,10 @@ class PallasBackend:
             has_sparse = c_sparse is not None and q_sparse is not None
             has_dense = c_dense is not None and q_dense is not None
             nnz = c_sparse.indices.shape[-1] if has_sparse else 0
+            q_terms = q_sparse.indices.shape[-1] if has_sparse else 0
             dd = c_dense.shape[-1] if has_dense else 0
             tile = self._fused_tile(
-                n, b, k_eff, space.vocab_size, nnz, dd,
+                n, b, k_eff, q_terms, nnz, dd,
                 val_itemsize=(c_sparse.values.dtype.itemsize
                               if has_sparse else 4),
                 dense_itemsize=(c_dense.dtype.itemsize if has_dense else 4))
@@ -483,7 +487,7 @@ class PallasBackend:
                 q_sparse, q_dense, c_sparse, c_dense, space.vocab_size,
                 k_eff, w_dense=w_dense, w_sparse=w_sparse,
                 dense_kind=getattr(space, "dense_kind", "ip"),
-                tile_n=tile, n_valid=n_valid, interpret=self._interpret())
+                tile_n=tile, n_valid=n_valid)
         else:
             head = _empty_topk(b)
         return (head if k_eff == k
@@ -624,7 +628,7 @@ class GraphANNBackend:
     ``kernel=True`` runs the traversal through the fused Pallas hop
     kernel (``kernels/beam_topk.py``: per-hop neighbor gather + score +
     top-``ef`` merge in one on-device pass over a packed visited
-    bitmask; interpret mode off-TPU) instead of the jnp hop loop —
+    bitmask) instead of the jnp hop loop —
     same declared budget and recall tier, sub-linear per-hop cost.  The
     kernel path inherits the Pallas capability matrix (dense ip/l2,
     sparse ip, fused with dense_kind='ip', contract dtypes): anything
@@ -632,7 +636,10 @@ class GraphANNBackend:
     ``resolve_backend`` falls back to reference.  ``ef * degree`` is
     additionally capped by the kernel's VMEM candidate budget
     (``beam_topk.MAX_BEAM_CANDIDATES``) — oversized budgets raise at
-    construction of the search, not inside the kernel."""
+    construction of the search, not inside the kernel.  The hop kernel
+    does not compile for TPU yet: there ``kernel=True`` raises at
+    construction with the compiler's reason
+    (``beam_topk.check_beam_platform``)."""
 
     degree: int = 16
     rounds: int = 6
@@ -642,6 +649,11 @@ class GraphANNBackend:
     seed: int = 0
     kernel: bool = False
     name = "graph_ann"
+
+    def __post_init__(self):
+        if self.kernel:
+            from repro.kernels.beam_topk import check_beam_platform
+            check_beam_platform()
 
     @property
     def identity(self) -> str:
@@ -710,10 +722,9 @@ class GraphANNBackend:
             check_beam_budget(self.ef, self.degree)
         search_corpus, index = self._index(space, corpus, n_valid)
         if self.kernel:
-            interpret = jax.default_backend() != "tpu"
             head = graph_ann_lib.kernel_beam_search(
                 space, query_repr, search_corpus, index, n_valid,
-                k=k_eff, ef=self.ef, hops=self.hops, interpret=interpret)
+                k=k_eff, ef=self.ef, hops=self.hops)
         else:
             head = graph_ann_lib.beam_search(
                 space, query_repr, search_corpus, index, n_valid,
@@ -883,7 +894,7 @@ def resolve_backend(backend="auto", space=None, corpus=None,
     searchable; it just takes the library path).  With ``space``/
     ``corpus`` omitted the capability check is skipped — the caller only
     wants the instance (e.g. a label at endpoint registration).
-    ``kwargs`` (``tile_n``, ``interpret``; for ANN backends ``ef``,
+    ``kwargs`` (``tile_n``; for ANN backends ``ef``,
     ``rerank_qty``, ...) reach the named backend's constructor.
     """
     if backend is None:

@@ -156,8 +156,6 @@ def sharded_exact_topk(
     O(B·k·shards) versus O(B·N) for gathering scores, which is the whole
     point of pushing top-k below the collective.
     """
-    from jax.experimental.shard_map import shard_map
-
     n_shards = mesh.shape[corpus_axis]
     n = corpus.shape[0]
     assert n % n_shards == 0, f"corpus rows {n} % shards {n_shards} != 0"
@@ -176,11 +174,11 @@ def sharded_exact_topk(
         return merge_topk(TopK(all_s, all_i), k)
 
     other_axes = tuple(a for a in mesh.axis_names if a != corpus_axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(*([None] * queries.ndim)), P(corpus_axis, *([None] * (corpus.ndim - 1)))),
         out_specs=TopK(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(queries, corpus)

@@ -381,7 +381,6 @@ def kernel_beam_search(
     ef: int = 64,
     hops: int | None = None,
     qb: int | None = None,
-    interpret: bool = True,
 ) -> TopK:
     """``beam_search`` through the fused Pallas traversal kernel.
 
@@ -409,7 +408,7 @@ def kernel_beam_search(
     k0 = min(ef, e)
     if isinstance(space, spaces_lib.DenseSpace):
         tk = ops.mips_topk(queries, entries, k0, tile_n=min(2048, e),
-                           space=space.kind, interpret=interpret, n_valid=e)
+                           space=space.kind, n_valid=e)
     else:
         q_sparse = (queries if isinstance(space, spaces_lib.SparseSpace)
                     else queries.sparse if qd is not None else None)
@@ -420,7 +419,7 @@ def kernel_beam_search(
         tk = ops.fused_topk(q_sparse, q_dense, e_sparse, e_dense, vocab,
                             k0, w_dense=w_dense, w_sparse=w_sparse,
                             dense_kind=dense_kind, tile_n=min(1024, e),
-                            n_valid=e, interpret=interpret)
+                            n_valid=e)
     init_s = tk.scores
     init_ids = index.entry_ids[tk.indices]
     if k0 < ef:
@@ -433,4 +432,4 @@ def kernel_beam_search(
     return ops.beam_topk(qd, q_dense, init_s, init_ids, index.neighbors,
                          c_idx, c_val, c_dense, k, int(hops), int(n_items),
                          w_dense=w_dense, w_sparse=w_sparse,
-                         dense_kind=dense_kind, qb=qb, interpret=interpret)
+                         dense_kind=dense_kind, qb=qb)

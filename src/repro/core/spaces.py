@@ -24,10 +24,11 @@ roughly double the effective scan bandwidth), but **scores always
 accumulate and emit in f32**: every scoring path upcasts its operands
 before the first multiply.  Since an elementwise cast commutes with
 tiling, all execution backends (reference / streaming / pallas — whose
-kernels upcast per tile) stay bit-identical to each other *within* a
-corpus dtype; across dtypes the bf16 tier is held to a recall@k == 1.0
-vs-f32-oracle + bounded-ULP score-error contract instead
-(``tests/_precision.py``; docs/ARCHITECTURE.md "Precision contract").
+kernels upcast per tile) agree with each other *within* a corpus dtype
+under the f32 rule; across dtypes the bf16 tier is held to a
+recall@k == 1.0 vs-f32-oracle + bounded-ULP score-error contract
+instead (``core/precision.py``; docs/ARCHITECTURE.md "Precision
+contract").
 """
 
 from __future__ import annotations
@@ -133,30 +134,34 @@ def _accum_f32(x: jax.Array) -> jax.Array:
             if jnp.dtype(x.dtype).itemsize < 4 else x)
 
 
+# Every f32 contraction of a scoring path runs at full f32 precision.  On a
+# TPU the default precision of an f32 matmul is one bf16 pass, which
+# would make the f32 oracle a bf16-rounded answer; on a CPU the setting
+# changes no bit.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def dense_scores(kind: str, q: jax.Array, d: jax.Array, p: float = 2.0) -> jax.Array:
     """All-pairs dense scores [B, N] for query [B, D] vs docs [N, D].
 
     Sub-f32 operands upcast to f32 before the first multiply (a no-op
     for f32 inputs), so bf16-resident corpora accumulate in f32 — the
     same arithmetic the Pallas kernels run after their per-tile
-    upcasts, which is what keeps all backends bit-identical per corpus
-    dtype."""
+    upcasts — and every contraction runs at :data:`HIGHEST`."""
     q = _accum_f32(q)
     d = _accum_f32(d)
     if kind == "ip":
-        return q @ d.T
+        return jnp.matmul(q, d.T, precision=HIGHEST)
     if kind == "cosine":
         qn = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
         dn = d / jnp.maximum(jnp.linalg.norm(d, axis=-1, keepdims=True), 1e-12)
-        return qn @ dn.T
+        return jnp.matmul(qn, dn.T, precision=HIGHEST)
     if kind == "l2":
         # -||q - d||^2 via the matmul identity: MXU-friendly, no B*N*D blowup.
-        # Norms via einsum (a dot_general): unlike a fused mul+reduce, its
-        # accumulation order is stable across eager/jit/scan contexts, so
-        # every execution backend reproduces these scores bit for bit.
-        q2 = jnp.einsum("bd,bd->b", q, q)[:, None]         # [B,1]
-        d2 = jnp.einsum("nd,nd->n", d, d)[None, :]         # [1,N]
-        return -(q2 + d2 - 2.0 * (q @ d.T))
+        # Norms via einsum (a dot_general), at full f32 precision.
+        q2 = jnp.einsum("bd,bd->b", q, q, precision=HIGHEST)[:, None]  # [B,1]
+        d2 = jnp.einsum("nd,nd->n", d, d, precision=HIGHEST)[None, :]  # [1,N]
+        return -(q2 + d2 - 2.0 * jnp.matmul(q, d.T, precision=HIGHEST))
     if kind == "lp":
         diff = jnp.abs(q[:, None, :] - d[None, :, :])      # [B,N,D] (small D only)
         return -jnp.sum(diff**p, axis=-1) ** (1.0 / p)
@@ -223,7 +228,7 @@ def weighted_mix(parts, weights) -> jax.Array:
     scoring path (library, streaming tiles, the Pallas fused kernel) goes
     through this exact arithmetic."""
     return jnp.einsum("...c,c->...", jnp.stack(parts, axis=-1),
-                      jnp.asarray(weights, jnp.float32))
+                      jnp.asarray(weights, jnp.float32), precision=HIGHEST)
 
 
 class FusedVectors(NamedTuple):

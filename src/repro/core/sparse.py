@@ -37,6 +37,11 @@ __all__ = [
 ]
 
 
+# f32 contractions at full precision (a TPU's default is one bf16 pass);
+# the same setting as ``spaces.HIGHEST``, which imports this module
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def _accum_f32(x: jax.Array) -> jax.Array:
     """Upcast sub-f32 values (bf16/f16 residency) to f32 for
     accumulation; f32 passes through and wider dtypes (outside the
@@ -152,7 +157,8 @@ def sparse_inner_qbatch_docs(
     qd = jnp.pad(qd, ((0, 0), (0, 1)))             # trash slot for pad_id
     # [B, N, NNZ] gather — tiled variant below bounds the intermediate.
     picked = qd[:, docs.indices]                   # [B, N, NNZ]
-    return jnp.einsum("bnk,nk->bn", picked, _accum_f32(docs.values))
+    return jnp.einsum("bnk,nk->bn", picked, _accum_f32(docs.values),
+                      precision=_HIGHEST)
 
 
 def sparse_inner_tiled(
@@ -177,7 +183,8 @@ def sparse_inner_tiled(
     def body(carry, tile):
         ti, tv = tile
         picked = qd[:, ti]                          # [B, tile, NNZ]
-        return carry, jnp.einsum("bnk,nk->bn", picked, _accum_f32(tv))
+        return carry, jnp.einsum("bnk,nk->bn", picked, _accum_f32(tv),
+                                 precision=_HIGHEST)
 
     _, out = jax.lax.scan(body, None, (di, dv))
     return jnp.moveaxis(out, 0, 1).reshape(q.indices.shape[0], n)

@@ -47,8 +47,6 @@ def dp_allreduce_grads(grads, mesh, dp_axes=("pod", "data"), compress=None):
     """Explicit DP gradient all-reduce via shard_map (the implicit GSPMD
     path fuses this into the train step; the explicit path exists so
     compression can intercept the cross-pod hop)."""
-    from jax.experimental.shard_map import shard_map
-
     present = [a for a in dp_axes if a in mesh.axis_names]
     if not present:
         return grads
@@ -64,5 +62,5 @@ def dp_allreduce_grads(grads, mesh, dp_axes=("pod", "data"), compress=None):
             g)
 
     spec = jax.tree.map(lambda _: P(*[None]), grads)
-    return shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                     check_rep=False)(grads)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                         check_vma=False)(grads)

@@ -14,17 +14,10 @@ import numpy as np
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
     """jax.make_mesh with explicit Auto axis types (silences the 0.9 default
-    flip; our models rely on GSPMD propagation + explicit constraints).
-
-    jax < 0.5 has neither ``jax.sharding.AxisType`` nor the ``axis_types``
-    kwarg — every axis is implicitly Auto there, so plain make_mesh is the
-    same thing."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    flip; our models rely on GSPMD propagation + explicit constraints)."""
     return jax.make_mesh(
         tuple(shape), tuple(axes),
-        axis_types=(axis_type.Auto,) * len(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )
 
 

@@ -6,7 +6,8 @@
 #   fused_topk.py    sparse+dense scoring AND top-k selection in one pass —
 #                    the `pallas` execution backend for fused/sparse spaces
 # ops.py = jitted wrappers (library drop-ins); ref.py = pure-jnp oracles.
-# Validated in interpret mode (tests/test_kernels.py); TPU is the target
-# (BlockSpec tiling notes in each kernel's docstring).
+# Compiled by Mosaic on a TPU, interpreted elsewhere (platform.py);
+# validated in interpret mode (tests/test_kernels.py) and compiled for a
+# described v5e (tests/test_tpu_compile.py).
 
 from repro.kernels import ops, ref  # noqa: F401

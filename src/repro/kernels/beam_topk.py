@@ -63,12 +63,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.mips_topk import NEG
+from repro.kernels.platform import interpreted, pallas_call
 
 # Cap on the per-hop candidate block C = ef * R (see the VMEM budget
 # note above): at f32 x D=128 this bounds the gathered block near
 # 16 MiB per query tile — beyond it the kernel refuses instead of
 # compiling something that cannot fit VMEM on any TPU generation.
 MAX_BEAM_CANDIDATES = 32768
+
+
+# What the TPU v5e compiler (jax 0.9.0, libtpu 0.0.34) says of the hop
+# kernel: it reads the visited bitmask, the adjacency and the corpus —
+# refs left in HBM (memory_space=ANY) — with plain indexed loads, where
+# Mosaic needs explicit async copies into VMEM.
+TPU_REFUSAL = ("Loads are only allowed on VMEM and SMEM references. ANY "
+               "memory space can only be accessed using async_copy.")
+
+
+def check_beam_platform():
+    """Refuse the kernel traversal where it would be compiled for a TPU,
+    with the compiler's reason, instead of failing deep in lowering."""
+    if not interpreted():
+        raise NotImplementedError(
+            "the graph-ANN beam kernel does not compile for TPU (Mosaic: "
+            f"{TPU_REFUSAL!r}); serve graph_ann with kernel=False")
 
 
 def visited_words(n: int) -> int:
@@ -238,7 +256,7 @@ def _hop_kernel(*refs, n: int, ef: int, r: int, qb: int, nnz: int,
 def beam_hop_pallas(qdensified, q_dense, beam_s, beam_i, visited, neighbors,
                     c_idx, c_val, c_dense, *, n_valid: int,
                     w_dense=None, w_sparse=None, dense_kind: str = "ip",
-                    qb: int | None = None, interpret: bool = True):
+                    qb: int | None = None):
     """One fused hop: ``(beam_s, beam_i, words, addend)``.
 
     ``beam_s/beam_i`` [B, ef] are the running beam (descending, sentinel
@@ -306,7 +324,7 @@ def beam_hop_pallas(qdensified, q_dense, beam_s, beam_i, visited, neighbors,
         _hop_kernel, n=n_valid, ef=ef, r=r, qb=qb, nnz=nnz,
         weighted=weighted, dense_kind=dense_kind,
         has_dense=has_dense, has_sparse=has_sparse)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(b // qb,),
         in_specs=in_specs,
@@ -322,15 +340,13 @@ def beam_hop_pallas(qdensified, q_dense, beam_s, beam_i, visited, neighbors,
             jax.ShapeDtypeStruct((b, c), jnp.int32),
             jax.ShapeDtypeStruct((b, c), jnp.uint32),
         ],
-        interpret=interpret,
     )(*operands)
 
 
 def beam_search_pallas(qdensified, q_dense, beam_s, beam_i, visited,
                        neighbors, c_idx, c_val, c_dense, *, n_valid: int,
                        hops: int, w_dense=None, w_sparse=None,
-                       dense_kind: str = "ip", qb: int | None = None,
-                       interpret: bool = True):
+                       dense_kind: str = "ip", qb: int | None = None):
     """``hops`` fused hops under a ``lax.scan``: the beam and the packed
     visited bitmask are the scan carry; each step runs the hop kernel
     and commits its mark-deltas (valid candidates are unique and unseen,
@@ -344,7 +360,7 @@ def beam_search_pallas(qdensified, q_dense, beam_s, beam_i, visited,
         bs, bi, words, addend = beam_hop_pallas(
             qdensified, q_dense, bs, bi, v, neighbors, c_idx, c_val,
             c_dense, n_valid=n_valid, w_dense=w_dense, w_sparse=w_sparse,
-            dense_kind=dense_kind, qb=qb, interpret=interpret)
+            dense_kind=dense_kind, qb=qb)
         v = v.at[rows, words].add(addend, mode="drop")
         return (bs, bi, v), None
 

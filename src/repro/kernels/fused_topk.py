@@ -6,9 +6,10 @@ training data") executed as a single corpus scan.
 Per grid step over corpus tiles:
 
     score[b, n] = w_dense  * dense_kind(q_dense[b], c_dense[n])     (MXU)
-                + w_sparse * sum_k qd[b, c_idx[n, k]] * c_val[n, k] (VPU)
+                + w_sparse * sum_k q[b](c_idx[n, k]) * c_val[n, k]  (VPU+MXU)
     fold the [B, TILE_N] tile scores into the running top-k carried in
-    VMEM scratch (K rounds of max/argmax/mask, from kernels/mips_topk.py)
+    VMEM scratch (K rounds of max/first-position/mask, from
+    kernels/mips_topk.py)
 
 so the [B, N] score matrix never exists anywhere — not in HBM (as in
 ``kernels/sparse_dense.py`` + host ``lax.top_k``) and not on the host.
@@ -17,48 +18,38 @@ scan+select is dense-only, Lucene's inverted scan is sparse-only; here
 the mixing happens *inside* the kernel, with the component weights as
 compile-time constants.
 
+The sparse part is a term match, not a gather.  Queries are few and
+short, so they stay in padded COO: their ``B*T`` term ids form one
+column, and their weights a block-diagonal ``[B, B*T]`` matrix.  Per
+tile, each of the NNZ document slots is compared with every query term
+and its value accumulated where the ids match (``hits[(b, t), n]``,
+VPU); one MXU contraction with the query weights then gives
+``sum_t q_w[b, t] * hits[(b, t), n]`` — the sparse inner product.  A
+gather of a densified ``[B, V+1]`` query table at per-lane indices would
+be the direct form, but Mosaic refuses it, and the table would have to
+sit in VMEM; the match costs ``B*T*NNZ`` compares per row and holds no
+vocabulary-sized state.  Query slots whose id is out of ``[0, V)`` (the
+``V`` padding id) carry the id ``-1``, which no document slot holds.
+
 Either component may be absent (static ``has_dense`` / ``has_sparse``):
 the same kernel serves pure-dense fused vectors, pure-sparse fused
 vectors, and plain ``SparseSpace`` corpora (a ``None`` weight leaves a
-single component unscaled, matching the library path's arithmetic
-exactly; mixing two components always takes explicit weights, as
-``FusedSpace`` does).
+single component unscaled, as the library path does; mixing two
+components always takes explicit weights, as ``FusedSpace`` does).
+Selection breaks ties toward the lower corpus row id, like
+``lax.top_k``.
 
-Bit-identity contract (the one the dense backends already enforce): every
-per-element arithmetic order mirrors the library path —
+Precision: operands upcast to f32 at the top of every tile and both
+contractions run at ``Precision.HIGHEST``, so scores are f32 whatever the
+residency dtype.  Against the library path (``FusedSpace.score_batch``)
+they agree within the f32 score bound of ``tests/_precision.py``, not
+bit for bit: the summation order differs (``tests/test_fused_backend.py``).
 
-  * dense ip: one ``dot_general`` contraction, identical to
-    ``spaces.dense_scores``' ``q @ d.T``;
-  * dense l2: einsum norms + the exact ``-(q2 + c2 - 2s)`` grouping of
-    ``spaces.dense_scores``;
-  * sparse: gather the densified query table at the tile's padded-COO
-    indices and reduce over nnz with the same ``einsum("bnk,nk->bn")``
-    as ``core.sparse.sparse_inner_qbatch_docs``;
-  * mixing: ``w_dense * dense + w_sparse * sparse`` in the library's
-    association order (``FusedSpace.score_batch``);
-  * selection: ``_fold_topk`` breaks ties toward the lower corpus row id,
-    like ``lax.top_k``.
-
-So f32 scores and indices are bit-identical to the reference backend in
-every compilation context (eager / jit / scan) — swept in
-``tests/test_fused_backend.py``.
-
-Precision: operands upcast to f32 at the top of every tile (the
-``astype`` calls below), so bf16-resident corpora — half the COO/dense
-value stream — accumulate exactly like the library paths, which upcast
-at the same points (``core.sparse`` densifies in the storage dtype and
-THEN casts the table, mirroring this kernel's whole-table upcast).
-Within the bf16 tier results stay bit-identical across backends; across
-tiers the recall/ULP contract applies (``tests/test_bf16.py``, the
-``bf16`` CI marker; scores always emit f32).
-
-TPU-target layout notes: TILE_N and the dense D should be multiples of
-128; the per-nnz-column gathers lower to dynamic-slice-per-lane on Mosaic
-(documented fallback: one-hot matmul per nnz slice over a blocked
-vocabulary); the ``[B, V+1]`` densified query table must fit VMEM next to
-the tile stream — ``core.backends.auto_tile_n`` budgets this from
-``launch/roofline.py``.  Interpret mode (CI, CPU) runs the identical
-arithmetic.
+Compiled by Mosaic on a TPU and interpreted elsewhere
+(``kernels.platform``); ``tests/test_tpu_compile.py`` compiles it for a
+described v5e at vocabulary 30,522 and nnz 64.  TILE_N and the dense D
+should be multiples of 128; ``core.backends.auto_tile_n`` budgets the
+tile's VMEM working set, ``hits`` included.
 """
 
 from __future__ import annotations
@@ -70,20 +61,68 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.mips_topk import NEG, _fold_topk
+from repro.kernels.mips_topk import NEG, _fold_topk, score_tile
+from repro.kernels.platform import pallas_call
+
+
+def query_terms(q_idx: jax.Array, q_val: jax.Array, vocab_size: int):
+    """Padded-COO queries [B, T] -> the kernel's query operands: term ids
+    as one column ``i32[B*T, 1]`` (out-of-vocabulary and padding slots
+    become ``-1``, which matches nothing) and block-diagonal weights
+    ``f32[B, B*T]``."""
+    b, t = q_idx.shape
+    ok = (q_idx >= 0) & (q_idx < vocab_size)
+    ids = jnp.where(ok, q_idx, -1).astype(jnp.int32).reshape(b * t, 1)
+    val = jnp.where(ok, q_val.astype(jnp.float32), 0.0)
+    w = (jnp.eye(b, dtype=jnp.float32)[:, :, None] * val[None, :, :])
+    return ids, w.reshape(b, b * t)
+
+
+def sparse_scratch(bt: int, nnz: int, tile_n: int):
+    """VMEM scratch :func:`sparse_tile` needs: the tile's COO ids and
+    values transposed to ``[NNZ, TILE_N]``, and the ``[B*T, TILE_N]``
+    hit accumulator."""
+    return [pltpu.VMEM((nnz, tile_n), jnp.int32),
+            pltpu.VMEM((nnz, tile_n), jnp.float32),
+            pltpu.VMEM((bt, tile_n), jnp.float32)]
+
+
+def sparse_tile(q_ids: jax.Array, q_w: jax.Array, c_idx: jax.Array,
+                c_val: jax.Array, idx_scr, val_scr, hits_scr) -> jax.Array:
+    """Sparse inner products of a corpus tile inside a kernel: ``q_ids``
+    i32[B*T, 1], ``q_w`` f32[B, B*T] (from :func:`query_terms`),
+    ``c_idx``/``c_val`` [TILE_N, NNZ] -> f32[B, TILE_N].
+
+    The tile is transposed once so that each COO slot is a lane row, and
+    the slots are visited by a loop rather than unrolled, which keeps
+    the working set to one ``hits`` block."""
+    idx_scr[...] = c_idx.T
+    val_scr[...] = c_val.astype(jnp.float32).T
+    hits_scr[...] = jnp.zeros_like(hits_scr)
+
+    def slot(j, carry):
+        ids = idx_scr[pl.ds(j, 1), :]                        # [1, TILE_N]
+        vals = val_scr[pl.ds(j, 1), :]
+        hits_scr[...] += jnp.where(ids == q_ids, vals, 0.0)  # [B*T, TILE_N]
+        return carry
+
+    jax.lax.fori_loop(0, c_idx.shape[1], slot, 0)
+    return jax.lax.dot_general(q_w, hits_scr[...], (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _kernel(*refs, k: int, tile_n: int, n_tiles: int, n_valid: int,
-            nnz: int, weighted: bool, dense_kind: str,
-            has_dense: bool, has_sparse: bool):
+            w_dense, w_sparse, dense_kind: str, has_dense: bool,
+            has_sparse: bool):
     it = iter(refs)
-    w_ref = next(it) if weighted else None           # [1, C] mix weights
-    qd_ref = next(it) if has_sparse else None        # [B, V+1] densified
+    qids_ref = next(it) if has_sparse else None      # [B*T, 1] i32
+    qw_ref = next(it) if has_sparse else None        # [B, B*T]
     qdense_ref = next(it) if has_dense else None     # [B, Dd]
     cidx_ref = next(it) if has_sparse else None      # [TILE_N, NNZ] i32
     cval_ref = next(it) if has_sparse else None      # [TILE_N, NNZ]
     cdense_ref = next(it) if has_dense else None     # [TILE_N, Dd]
-    out_s_ref, out_i_ref, s_scr, i_scr = it
+    out_s_ref, out_i_ref, s_scr, i_scr, *sparse_scr = it
 
     t = pl.program_id(0)
 
@@ -92,40 +131,17 @@ def _kernel(*refs, k: int, tile_n: int, n_tiles: int, n_valid: int,
         s_scr[...] = jnp.full_like(s_scr, NEG)
         i_scr[...] = jnp.zeros_like(i_scr)
 
-    parts = []
+    total = None
     if has_dense:
-        q = qdense_ref[...].astype(jnp.float32)
-        c = cdense_ref[...].astype(jnp.float32)
-        dense = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        if dense_kind == "l2":
-            # exact grouping of spaces.dense_scores — see mips_topk.py
-            q2 = jnp.einsum("bd,bd->b", q, q)[:, None]
-            c2 = jnp.einsum("nd,nd->n", c, c)[None, :]
-            dense = -(q2 + c2 - 2.0 * dense)
-        parts.append(dense)
+        total = score_tile(qdense_ref[...], cdense_ref[...], dense_kind)
+        if w_dense is not None:
+            total = w_dense * total
     if has_sparse:
-        qd = qd_ref[...].astype(jnp.float32)
-        idx = cidx_ref[...]
-        val = cval_ref[...].astype(jnp.float32)
-        if nnz:
-            # one gather per static nnz column, reduced with the SAME
-            # einsum as sparse_inner_qbatch_docs so the k-accumulation
-            # order matches the library path element for element
-            picked = jnp.stack([qd[:, idx[:, j]] for j in range(nnz)],
-                               axis=-1)               # [B, TILE_N, NNZ]
-            sparse = jnp.einsum("bnk,nk->bn", picked, val)
-        else:
-            sparse = jnp.zeros((qd.shape[0], idx.shape[0]), jnp.float32)
-        parts.append(sparse)
-    if weighted:
-        # the library's exact mixing arithmetic (spaces.weighted_mix):
-        # ONE einsum over the stacked component axis — an elementwise
-        # w_d*dense + w_s*sparse would FMA-fuse under jit and drift a bit
-        total = jnp.einsum("...c,c->...", jnp.stack(parts, axis=-1),
-                           w_ref[...][0])
-    else:
-        total = parts[0]            # SparseSpace: single unscaled part
+        sparse = sparse_tile(qids_ref[...], qw_ref[...], cidx_ref[...],
+                             cval_ref[...], *sparse_scr)
+        if w_sparse is not None:
+            sparse = w_sparse * sparse
+        total = sparse if total is None else total + sparse
 
     base = t * tile_n
     ids = base + jax.lax.broadcasted_iota(jnp.int32, total.shape, 1)
@@ -143,13 +159,12 @@ def _kernel(*refs, k: int, tile_n: int, n_tiles: int, n_valid: int,
         out_i_ref[...] = i_scr[...]
 
 
-def fused_topk_pallas(qdensified, q_dense, c_idx, c_val, c_dense, k: int,
+def fused_topk_pallas(q_ids, q_w, q_dense, c_idx, c_val, c_dense, k: int,
                       w_dense=None, w_sparse=None, tile_n: int = 1024,
-                      n_valid: int | None = None, dense_kind: str = "ip",
-                      interpret: bool = True):
+                      n_valid: int | None = None, dense_kind: str = "ip"):
     """One-pass fused score + top-k: (scores [B, K], ids [B, K]) descending.
 
-    ``qdensified`` [B, V+1] (zero trash column last) + ``c_idx``/``c_val``
+    ``q_ids``/``q_w`` (from :func:`query_terms`) + ``c_idx``/``c_val``
     [N, NNZ] form the sparse component; ``q_dense`` [B, Dd] + ``c_dense``
     [N, Dd] the dense one.  Pass ``None`` for an absent component (at
     least one required).  ``w_dense``/``w_sparse``: static mixing weights;
@@ -174,26 +189,23 @@ def fused_topk_pallas(qdensified, q_dense, c_idx, c_val, c_dense, k: int,
                          "w_sparse (pass 1.0 explicitly for an unweighted "
                          "sum)")
     n = (c_dense if has_dense else c_idx).shape[0]
-    b = (q_dense if has_dense else qdensified).shape[0]
+    b = (q_dense if has_dense else q_w).shape[0]
     assert n % tile_n == 0, (n, tile_n)
     n_tiles = n // tile_n
     n_valid = n if n_valid is None else n_valid
-    nnz = c_idx.shape[1] if has_sparse else 0
 
     in_specs, operands = [], []
-    if weighted:
-        c_parts = len(weights)
-        in_specs.append(pl.BlockSpec((1, c_parts), lambda t: (0, 0)))
-        operands.append(jnp.asarray([weights], jnp.float32))
     if has_sparse:
-        vp1 = qdensified.shape[1]
-        in_specs.append(pl.BlockSpec((b, vp1), lambda t: (0, 0)))
-        operands.append(qdensified)                  # query table resident
+        bt = q_ids.shape[0]
+        in_specs.append(pl.BlockSpec((bt, 1), lambda t: (0, 0)))
+        in_specs.append(pl.BlockSpec((b, bt), lambda t: (0, 0)))
+        operands.extend([q_ids, q_w])                # query terms resident
     if has_dense:
         dd = q_dense.shape[1]
         in_specs.append(pl.BlockSpec((b, dd), lambda t: (0, 0)))
         operands.append(q_dense)                     # queries resident
     if has_sparse:
+        nnz = c_idx.shape[1]
         in_specs.append(pl.BlockSpec((tile_n, nnz), lambda t: (t, 0)))
         in_specs.append(pl.BlockSpec((tile_n, nnz), lambda t: (t, 0)))
         operands.extend([c_idx, c_val])              # COO tiles streamed
@@ -203,9 +215,10 @@ def fused_topk_pallas(qdensified, q_dense, c_idx, c_val, c_dense, k: int,
 
     kernel = functools.partial(
         _kernel, k=k, tile_n=tile_n, n_tiles=n_tiles, n_valid=n_valid,
-        nnz=nnz, weighted=weighted, dense_kind=dense_kind,
-        has_dense=has_dense, has_sparse=has_sparse)
-    out_s, out_i = pl.pallas_call(
+        w_dense=w_dense if has_dense else None,
+        w_sparse=w_sparse if has_sparse else None,
+        dense_kind=dense_kind, has_dense=has_dense, has_sparse=has_sparse)
+    out_s, out_i = pallas_call(
         kernel,
         grid=(n_tiles,),
         in_specs=in_specs,
@@ -220,7 +233,6 @@ def fused_topk_pallas(qdensified, q_dense, c_idx, c_val, c_dense, k: int,
         scratch_shapes=[
             pltpu.VMEM((b, k), jnp.float32),
             pltpu.VMEM((b, k), jnp.int32),
-        ],
-        interpret=interpret,
+        ] + (sparse_scratch(bt, nnz, tile_n) if has_sparse else []),
     )(*operands)
     return out_s, out_i

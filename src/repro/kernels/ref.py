@@ -9,17 +9,14 @@ import jax.numpy as jnp
 
 def mips_topk_ref(queries: jax.Array, corpus: jax.Array, k: int,
                   n_valid: int | None = None, space: str = "ip"):
-    """Exact top-k via full score matrix + lax.top_k."""
-    q = queries.astype(jnp.float32)
-    c = corpus.astype(jnp.float32)
-    s = q @ c.T
-    if space == "l2":
-        # einsum norms + grouping as in spaces.dense_scores so the oracle
-        # is bit-exact against both the kernel and the library path
-        s = -(jnp.einsum("bd,bd->b", q, q)[:, None]
-              + jnp.einsum("nd,nd->n", c, c)[None, :] - 2.0 * s)
+    """Exact top-k via full score matrix + lax.top_k (the library's
+    ``spaces.dense_scores`` arithmetic)."""
+    from repro.core.spaces import dense_scores
+
+    s = dense_scores(space, queries.astype(jnp.float32),
+                     corpus.astype(jnp.float32))
     if n_valid is not None:
-        mask = jnp.arange(c.shape[0])[None, :] < n_valid
+        mask = jnp.arange(corpus.shape[0])[None, :] < n_valid
         s = jnp.where(mask, s, jnp.finfo(jnp.float32).min)
     vals, idx = jax.lax.top_k(s, k)
     return vals, idx.astype(jnp.int32)
@@ -28,11 +25,12 @@ def mips_topk_ref(queries: jax.Array, corpus: jax.Array, k: int,
 def fused_score_ref(qdensified: jax.Array, q_dense: jax.Array,
                     c_idx: jax.Array, c_val: jax.Array, c_dense: jax.Array,
                     w_dense: float, w_sparse: float):
-    from repro.core.spaces import weighted_mix
+    from repro.core.spaces import HIGHEST, dense_scores, weighted_mix
 
-    dense = q_dense.astype(jnp.float32) @ c_dense.astype(jnp.float32).T
+    dense = dense_scores("ip", q_dense, c_dense)
     picked = qdensified.astype(jnp.float32)[:, c_idx]           # [B, N, NNZ]
-    sparse = jnp.einsum("bnk,nk->bn", picked, c_val.astype(jnp.float32))
+    sparse = jnp.einsum("bnk,nk->bn", picked, c_val.astype(jnp.float32),
+                        precision=HIGHEST)
     return weighted_mix([dense, sparse], [w_dense, w_sparse])
 
 
@@ -43,7 +41,7 @@ def fused_topk_ref(q_sparse, q_dense, c_sparse, c_dense, vocab_size: int,
     library paths (``spaces.dense_scores`` + ``sparse.
     sparse_inner_qbatch_docs``, the exact arithmetic ``FusedSpace.
     score_batch`` runs), selection via ``lax.top_k`` — so kernel tests pin
-    the library semantics, bit for bit.  ``None`` weights leave a
+    the library semantics (within the f32 score bound).  ``None`` weights leave a
     component unscaled (SparseSpace semantics); ``None`` components are
     skipped."""
     from repro.core import sparse as sp

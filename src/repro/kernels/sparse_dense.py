@@ -2,30 +2,20 @@
 representation, scored in one pass.
 
 score[b, n] = w_dense * <q_dense[b], c_dense[n]>
-            + w_sparse * sum_k qd[b, c_idx[n, k]] * c_val[n, k]
+            + w_sparse * sum_k q[b](c_idx[n, k]) * c_val[n, k]
 
 The dense component is an MXU matmul over the streamed corpus tile; the
-sparse component gathers the *densified query row* (queries are few — the
-[B, V+1] table sits in VMEM) at the tile's padded-COO indices and
-multiply-accumulates.  One kernel pass replaces NMSLIB's two per-component
-scans + host-side mixing.
+sparse component matches the tile's padded-COO term ids against the
+queries' terms and contracts the hits with the query weights
+(``fused_topk.sparse_tile`` — the same tile arithmetic as the one-pass
+score+select kernel in ``fused_topk.py``, which explains why it is a
+match and not a gather).  One kernel pass replaces NMSLIB's two
+per-component scans + host-side mixing.
 
-TPU-target notes:
-  * the NNZ gathers are static (unrolled): each is a vectorised gather of
-    one index column [TILE_N] from the query table, reduced with the same
-    ``einsum("bnk,nk->bn")`` as ``core.sparse.sparse_inner_qbatch_docs``
-    and mixed through the same one-einsum weight mix as
-    ``spaces.weighted_mix`` — so f32 scores are bit-identical to
-    ``FusedSpace.score_batch``.  On Mosaic the gather lowers to
-    dynamic-slice-per-lane; the documented fallback is a one-hot
-    [TILE_N, V_block] matmul per NNZ slice (MXU-friendly when the term
-    vocabulary is blocked).
-  * padding ids == V land in the table's zero column (V+1 wide), so no
-    branch is needed.
-
-Validated against ``ref.fused_score_ref`` in interpret mode
-(tests/test_kernels.py) over shape/dtype/weight sweeps; the one-pass
-score+select variant lives in ``fused_topk.py``.
+Compiled by Mosaic on a TPU and interpreted elsewhere
+(``kernels.platform``).  Validated against ``ref.fused_score_ref``
+(tests/test_kernels.py) under the f32 score bound of
+``tests/_precision.py``.
 """
 
 from __future__ import annotations
@@ -36,46 +26,37 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _kernel(w_ref, qd_ref, qdense_ref, cidx_ref, cval_ref, cdense_ref,
-            out_ref, *, nnz: int):
-    qd = qd_ref[...].astype(jnp.float32)          # [B, V+1] densified queries
-    qv = qdense_ref[...].astype(jnp.float32)      # [B, Dd]
-    cd = cdense_ref[...].astype(jnp.float32)      # [TILE_N, Dd]
-    dense = jax.lax.dot_general(qv, cd, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-
-    idx = cidx_ref[...]                           # [TILE_N, NNZ] i32
-    val = cval_ref[...].astype(jnp.float32)       # [TILE_N, NNZ]
-    picked = jnp.stack([qd[:, idx[:, j]] for j in range(nnz)],
-                       axis=-1)                   # [B, TILE_N, NNZ]
-    sparse = jnp.einsum("bnk,nk->bn", picked, val)
-
-    # the library's exact mixing arithmetic (spaces.weighted_mix): one
-    # einsum over the stacked component axis — see fused_topk.py
-    out_ref[...] = jnp.einsum("...c,c->...",
-                              jnp.stack([dense, sparse], axis=-1),
-                              w_ref[...][0])
+from repro.kernels.fused_topk import sparse_scratch, sparse_tile
+from repro.kernels.mips_topk import score_tile
+from repro.kernels.platform import pallas_call
 
 
-def fused_score_pallas(qdensified: jax.Array, q_dense: jax.Array,
+def _kernel(qids_ref, qw_ref, qdense_ref, cidx_ref, cval_ref, cdense_ref,
+            out_ref, *sparse_scr, w_dense: float, w_sparse: float):
+    dense = score_tile(qdense_ref[...], cdense_ref[...], "ip")
+    sparse = sparse_tile(qids_ref[...], qw_ref[...], cidx_ref[...],
+                         cval_ref[...], *sparse_scr)
+    out_ref[...] = w_dense * dense + w_sparse * sparse
+
+
+def fused_score_pallas(q_ids: jax.Array, q_w: jax.Array, q_dense: jax.Array,
                        c_idx: jax.Array, c_val: jax.Array,
                        c_dense: jax.Array, w_dense: float, w_sparse: float,
-                       tile_n: int = 1024, interpret: bool = True):
-    """qdensified [B, V+1] (zero pad column last), q_dense [B, Dd],
-    c_idx/c_val [N, NNZ], c_dense [N, Dd] -> scores [B, N]."""
-    b, vp1 = qdensified.shape
+                       tile_n: int = 1024):
+    """q_ids i32[B*T, 1] + q_w [B, B*T] (``fused_topk.query_terms``),
+    q_dense [B, Dd], c_idx/c_val [N, NNZ], c_dense [N, Dd] -> scores
+    [B, N]."""
+    b, bt = q_w.shape
     n, nnz = c_idx.shape
     dd = q_dense.shape[1]
     assert n % tile_n == 0, (n, tile_n)
-    kernel = functools.partial(_kernel, nnz=nnz)
-    weights = jnp.asarray([[w_dense, w_sparse]], jnp.float32)
-    return pl.pallas_call(
+    kernel = functools.partial(_kernel, w_dense=w_dense, w_sparse=w_sparse)
+    return pallas_call(
         kernel,
         grid=(n // tile_n,),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda t: (0, 0)),
-            pl.BlockSpec((b, vp1), lambda t: (0, 0)),
+            pl.BlockSpec((bt, 1), lambda t: (0, 0)),
+            pl.BlockSpec((b, bt), lambda t: (0, 0)),
             pl.BlockSpec((b, dd), lambda t: (0, 0)),
             pl.BlockSpec((tile_n, nnz), lambda t: (t, 0)),
             pl.BlockSpec((tile_n, nnz), lambda t: (t, 0)),
@@ -83,5 +64,5 @@ def fused_score_pallas(qdensified: jax.Array, q_dense: jax.Array,
         ],
         out_specs=pl.BlockSpec((b, tile_n), lambda t: (0, t)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
-        interpret=interpret,
-    )(weights, qdensified, q_dense, c_idx, c_val, c_dense)
+        scratch_shapes=sparse_scratch(bt, nnz, tile_n),
+    )(q_ids, q_w, q_dense, c_idx, c_val, c_dense)

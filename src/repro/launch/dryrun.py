@@ -44,7 +44,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             t_compile = time.time()
             mem = compiled.memory_analysis()
             hlo = compiled.as_text()
-            cost = RL.cost_dict(compiled)
+            cost = compiled.cost_analysis()
             full_flops = float(cost.get("flops", 0.0))
             full_bytes = float(cost.get("bytes accessed", 0.0))
             full_coll = RL.collective_bytes_from_hlo(hlo)
@@ -56,7 +56,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
                 probe = build_lm_probe(arch, shape_name, mesh)
                 pc = jax.jit(probe.fn, in_shardings=probe.in_shardings
                              ).lower(*probe.args).compile()
-                p_cost = RL.cost_dict(pc)
+                p_cost = pc.cost_analysis()
                 p_hlo = pc.as_text()
                 p_coll = RL.collective_bytes_from_hlo(p_hlo)
                 lcount = cell.cfg.n_layers

@@ -2,9 +2,11 @@
 
 Per DESIGN.md §7, for each (arch x shape x mesh) cell:
 
-    compute    = HLO_FLOPs / (chips * 197e12)          [bf16 TPU v5e]
-    memory     = HLO_bytes / (chips * 819e9)
-    collective = collective_bytes / (chips * links * 50e9)
+    compute    = HLO_FLOPs / (chips * peak bf16 FLOP/s)
+    memory     = HLO_bytes / (chips * HBM bytes/s)
+    collective = collective_bytes / (chips * links * ICI bytes/s per link)
+
+with the peaks of the device kind (:data:`PEAKS`, :func:`device_peaks`).
 
 HLO_FLOPs / HLO_bytes come from ``compiled.cost_analysis()`` (whole-program,
 all devices).  collective_bytes are parsed from the *optimized* HLO text:
@@ -23,12 +25,53 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-# TPU v5e hardware model.
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link (per direction)
-ICI_LINKS = 4              # links/chip in a 2D torus (16x16 pod slice)
-VMEM_BYTES = 16 * 2**20    # on-chip vector memory / core
+import jax
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """One chip's published peaks, plus the scoped VMEM a Pallas kernel
+    compiles under on it."""
+
+    bf16_flops: float          # dense bf16 FLOP/s
+    hbm_bytes_per_s: float
+    hbm_bytes: float
+    ici_bytes_per_s: float     # per link, per direction
+    ici_links: int             # links per chip in a 2D torus
+    scoped_vmem_bytes: int     # Mosaic's default scoped VMEM limit
+
+
+# Keyed by ``jax.Device.device_kind``.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s ICI over 4 links); the scoped VMEM limit is the one the
+# v5e compiler enforces on a kernel ("limit 16.00M" in its
+# out-of-VMEM error).
+PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12, hbm_bytes_per_s=819e9, hbm_bytes=16e9,
+        ici_bytes_per_s=50e9, ici_links=4, scoped_vmem_bytes=16 * 2**20),
+}
+
+# The chip the kernels are written for: its peaks price kernels that run
+# elsewhere (interpret mode on a CPU host runs the same tiles).
+TARGET_KIND = "TPU v5 lite"
+
+
+def device_peaks(kind: Optional[str] = None) -> DevicePeaks:
+    """Peaks of ``kind``, or of the default device when it is a TPU (of
+    :data:`TARGET_KIND` otherwise).  A TPU kind missing from
+    :data:`PEAKS` raises: pricing it with another chip's numbers would
+    tune tiles for the wrong machine."""
+    if kind is None:
+        dev = jax.devices()[0]
+        kind = dev.device_kind if dev.platform == "tpu" else TARGET_KIND
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise KeyError(
+            f"no peaks for device kind {kind!r}; add its published "
+            f"numbers, with their source, to launch.roofline.PEAKS "
+            f"(known: {sorted(PEAKS)})") from None
 
 
 def topk_tile_seconds(tile_n: int, *, b: int, k: int, bytes_per_row: float,
@@ -45,8 +88,9 @@ def topk_tile_seconds(tile_n: int, *, b: int, k: int, bytes_per_row: float,
     (``core.backends.auto_tile_n``) minimises per corpus row: small tiles
     pay the ``B*K^2`` fold term once per few rows, large tiles stop
     fitting the VMEM working set."""
-    compute = (flops_per_row * tile_n + b * k * (k + tile_n)) / PEAK_FLOPS
-    memory = (bytes_per_row * tile_n) / HBM_BW
+    peaks = device_peaks()
+    compute = (flops_per_row * tile_n + b * k * (k + tile_n)) / peaks.bf16_flops
+    memory = (bytes_per_row * tile_n) / peaks.hbm_bytes_per_s
     return max(compute, memory)
 
 def serving_scan_seconds(n_rows: int, *, b: int, k: int, bytes_per_row: float,
@@ -71,7 +115,8 @@ def serving_scan_seconds(n_rows: int, *, b: int, k: int, bytes_per_row: float,
     scan = n_tiles * topk_tile_seconds(tile_n, b=b, k=k,
                                        bytes_per_row=bytes_per_row,
                                        flops_per_row=flops_per_row)
-    merge = (b * k * n_shards * (k + 1.0)) / PEAK_FLOPS if n_shards > 1 else 0.0
+    merge = ((b * k * n_shards * (k + 1.0)) / device_peaks().bf16_flops
+             if n_shards > 1 else 0.0)
     return scan + merge
 
 
@@ -84,8 +129,9 @@ def serving_visit_seconds(n_visits: float, *, b: int, bytes_per_row: float,
     per-candidate distance (``flops_per_visit``) plus the beam fold."""
     if n_visits <= 0:
         return 0.0
-    compute = (b * n_visits * flops_per_visit) / PEAK_FLOPS
-    memory = (b * n_visits * bytes_per_row) / HBM_BW
+    peaks = device_peaks()
+    compute = (b * n_visits * flops_per_visit) / peaks.bf16_flops
+    memory = (b * n_visits * bytes_per_row) / peaks.hbm_bytes_per_s
     return max(compute, memory)
 
 
@@ -177,14 +223,16 @@ def analyze_terms(flops: float, bytes_accessed: float,
     quantities; the terms divide by single-chip peaks.  ``model_flops``
     is the GLOBAL analytic count, so the useful-compute ratio compares it
     against flops * n_chips."""
+    peaks = device_peaks()
     coll = float(sum(per_collective.values()))
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_accessed / HBM_BW
-    collective_s = coll / (ICI_LINKS * ICI_BW)
+    compute_s = flops / peaks.bf16_flops
+    memory_s = bytes_accessed / peaks.hbm_bytes_per_s
+    collective_s = coll / (peaks.ici_links * peaks.ici_bytes_per_s)
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     useful = (model_flops / (flops * n_chips)) if (model_flops and flops) else None
-    mem_lo_s = (resident_bytes / HBM_BW) if resident_bytes else None
+    mem_lo_s = ((resident_bytes / peaks.hbm_bytes_per_s) if resident_bytes
+                else None)
     bottleneck_lo = None
     if mem_lo_s is not None:
         terms_lo = {"compute": compute_s, "memory": mem_lo_s,
@@ -196,18 +244,9 @@ def analyze_terms(flops: float, bytes_accessed: float,
                     bottleneck_lo)
 
 
-def cost_dict(compiled) -> dict:
-    """Normalise ``compiled.cost_analysis()`` across jax versions: a dict on
-    jax >= 0.5, a single-element list of dicts on 0.4.x."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def analyze(compiled, n_chips: int, model_flops: Optional[float] = None,
             hlo_text: Optional[str] = None) -> Roofline:
-    cost = cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     txt = hlo_text if hlo_text is not None else compiled.as_text()

@@ -52,10 +52,15 @@ def cross_encoder_score(params, q_tokens: jax.Array, d_tokens: jax.Array,
 def make_proxy_scorer(params, cfg: TransformerConfig, ctx: ParallelCtx,
                       doc_tokens: jax.Array) -> Callable:
     """Adapter producing the (q_tokens, cand_ids) -> [B, C] signature the
-    retrieval pipeline's ProxyExtractor expects."""
+    retrieval pipeline's ProxyExtractor expects.
+
+    The weights and the document tokens are arguments of the jitted
+    scorer, not values it closes over: closed-over arrays would be
+    compiled into the TPU executable as constants (gigabytes at a
+    published width, and minutes of compile)."""
 
     @jax.jit
-    def score(q_tokens, cand_ids):
+    def score(params, doc_tokens, q_tokens, cand_ids):
         b, c = cand_ids.shape
         docs = doc_tokens[cand_ids]                      # [B, C, L]
         qq = jnp.repeat(q_tokens[:, None, :], c, axis=1)
@@ -63,7 +68,8 @@ def make_proxy_scorer(params, cfg: TransformerConfig, ctx: ParallelCtx,
         flat_d = docs.reshape(b * c, -1)
         return cross_encoder_score(params, flat_q, flat_d, cfg, ctx).reshape(b, c)
 
-    return score
+    return lambda q_tokens, cand_ids: score(params, doc_tokens, q_tokens,
+                                            cand_ids)
 
 
 class CrossEncoderReranker:
@@ -82,15 +88,24 @@ class CrossEncoderReranker:
         self.doc_tokens = jnp.asarray(doc_tokens)
         self._score = make_proxy_scorer(params, cfg, ctx, self.doc_tokens)
 
-    def rerank(self, q_tokens: jax.Array, cands, keep: int):
-        from repro.core.pipeline import _reorder
-
+    def _masked_scores(self, q_tokens: jax.Array, cands):
         mask = jnp.isfinite(cands.scores)
         # clamp masked ids to row 0 so the gather stays in bounds; their
         # scores are forced to -inf below regardless of what row 0 scores
         ids = jnp.where(mask, cands.indices, 0)
-        scores = jnp.where(mask, self._score(q_tokens, ids), -jnp.inf)
-        return _reorder(cands, scores, keep)
+        return jnp.where(mask, self._score(q_tokens, ids), -jnp.inf)
+
+    def warmup(self, q_tokens: jax.Array, cands):
+        """Compile (and run once) the scorer for these shapes, so that a
+        later timed ``rerank`` measures a compiled run
+        (``serving.funnel.FunnelPipeline`` calls this before seeding its
+        cost estimate)."""
+        return self._masked_scores(q_tokens, cands)
+
+    def rerank(self, q_tokens: jax.Array, cands, keep: int):
+        from repro.core.pipeline import _reorder
+
+        return _reorder(cands, self._masked_scores(q_tokens, cands), keep)
 
 
 def contrastive_loss(params, q_tokens: jax.Array, pos_doc_tokens: jax.Array,

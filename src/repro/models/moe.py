@@ -271,7 +271,6 @@ def moe_apply(params: dict, x: jax.Array, cfg: TransformerConfig,
         return y.reshape(b, s, d), aux
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.mesh_utils import mesh_axis_size
 
     mesh = ctx.mesh
@@ -322,11 +321,11 @@ def moe_apply(params: dict, x: jax.Array, cfg: TransformerConfig,
             aux = jax.lax.pmean(aux, tuple(mesh.axis_names))
             return y.reshape(bl, sl, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(w_specs, x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     y, aux = fn({k: params[k] for k in w_specs}, x)
     return y, aux

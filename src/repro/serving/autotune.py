@@ -227,10 +227,12 @@ def check_config(cfg: ServingConfig, k: int, space=None,
         if cfg.hops is not None and cfg.hops < 1:
             return "hops must be >= 1 (or None for the auto default)"
         if cfg.kernel:
-            from repro.kernels.beam_topk import check_beam_budget
+            from repro.kernels.beam_topk import (check_beam_budget,
+                                                 check_beam_platform)
             try:
                 check_beam_budget(cfg.ef, _GRAPH_DEGREE)
-            except ValueError as exc:
+                check_beam_platform()
+            except (ValueError, NotImplementedError) as exc:
                 return str(exc)
             if cfg.corpus_dtype not in PallasBackend._DTYPES:
                 return (f"graph_ann kernel path serves "

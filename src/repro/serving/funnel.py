@@ -221,6 +221,16 @@ class FunnelPipeline:
                         f"estimated rerank cost {1e3 * estimate:.2f}ms")
         return None
 
+    def _warm_rerank(self, q_tokens, cands):
+        """Compile the rerank stage before the run that seeds its cost
+        estimate: a reranker with a ``warmup(q_tokens, cands)`` hook (the
+        cross-encoder's jitted scorer) pays its compilation here, off the
+        clock, so the seeding sample is a compiled run and a compile can
+        never trip the budget for every later batch."""
+        warmup = getattr(self.rerank, "warmup", None)
+        if warmup is not None:
+            jax.block_until_ready(warmup(q_tokens, cands))
+
     def run_timed(self, query_repr, q_tokens=None, *,
                   elapsed_s: float = 0.0) -> Tuple[TopK, StageTrace]:
         """One batch through the staged funnel; returns the result and
@@ -261,6 +271,8 @@ class FunnelPipeline:
             if reason is not None:
                 fallback = True
             else:
+                if estimate is None:
+                    self._warm_rerank(q_tokens, cands)
                 t2 = now()
                 cands = jax.block_until_ready(
                     self.rerank.rerank(q_tokens, cands, self.rerank_keep))

@@ -20,12 +20,15 @@ process (and, with a mesh, across devices):
     backend=...)`` / :meth:`ShardedPipeline.with_backend` resolve a
     :mod:`repro.core.backends` backend against each shard's slice.
 
-Bit-identity: contiguous shards concatenated in row order preserve
+Identity: contiguous shards concatenated in row order preserve
 ``lax.top_k``'s tie-break (lower slot == lower global row id), and every
-per-row score is computed from exactly the same values as the unsharded
-scan — so for exact generators the sharded result equals the unsharded
-``RetrievalPipeline.run`` bit for bit (verified in
-``tests/test_sharded.py``).
+per-row score is computed from the same values as the unsharded scan —
+so for exact generators the sharded result equals the unsharded
+``RetrievalPipeline.run`` (bit for bit on the reference backend; under
+the f32 rule of ``core.precision`` on the kernel and tiled backends,
+whose sum order depends on the shard's shape — ``tests/test_sharded.py``).
+With a mesh, the per-shard ``B x k`` lists move to the first shard's
+device for the merge.
 
 A ``ShardedPipeline`` exposes ``run(query_repr, q_tokens)`` and
 ``generate(query_repr, k)``, so it registers behind a single
@@ -108,6 +111,17 @@ def shard_corpus(corpus, n_shards: int, *, ctx=None,
             piece = jax.device_put(piece, devices[i % len(devices)])
         shards.append(CorpusShard(piece, lo, hi - lo))
     return tuple(shards)
+
+
+def _on_one_device(parts):
+    """Per-shard candidate lists committed to different devices (shards
+    placed by a mesh) move to the first shard's device for the merge:
+    ``B * k`` values per shard, not the corpus."""
+    devices = [p.scores.devices() for p in parts]
+    if all(d == devices[0] for d in devices):
+        return parts
+    target = next(iter(devices[0]))
+    return [jax.device_put(p, target) for p in parts]
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -263,6 +277,8 @@ class ShardedPipeline:
             parts = list(self.executor.map(one, generators, self.shards))
         else:
             parts = [one(g, s) for g, s in zip(generators, self.shards)]
+        if not tracing:
+            parts = _on_one_device(parts)
         cat = concat_topk(parts)
         return merge_topk(cat, min(k, cat.scores.shape[1]))
 

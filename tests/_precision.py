@@ -1,33 +1,15 @@
-"""The two-tier precision contract, as shared test helpers.
+"""The precision contract, as shared test helpers.
 
-Every backend parity sweep in this suite enforces one of two tiers
-(docs/ARCHITECTURE.md "Precision contract"):
+The rule — *same ids under a planted margin, scores within a stated
+bound*, with its f32 and bf16 bounds and why they are what they are — is
+stated once, in ``src/repro/core/precision.py``, which the serving
+example and ``chip_smoke.py`` check against too.  This module turns its
+violation lists into assertions and builds the margin-planted data.
 
-  * **f32 tier — bitwise.**  All execution backends return bit-identical
-    f32 scores and indices (:func:`assert_topk_bitwise`).  This is the
-    historical contract and it is unchanged.
-
-  * **bf16 tier — bounded error.**  A corpus resident in bf16 cannot be
-    bit-identical to the f32 oracle (the inputs themselves were
-    rounded), so the contract splits in two:
-
-      1. *within* the bf16 tier, backends are still bitwise identical to
-         each other — every path upcasts the same stored bf16 values to
-         f32 before the first multiply, and an elementwise cast commutes
-         with tiling (:func:`assert_topk_bitwise` again, bf16 reference
-         as the anchor);
-      2. *across* tiers, the bf16 result must have recall@k == 1.0
-         against the f32 oracle and score error within
-         :data:`BF16_MAX_ULP` bf16 ULPs at the oracle's per-row score
-         scale (:func:`assert_bf16_oracle_contract`).
-
-The ULP bound: bf16 round-to-nearest moves an element by at most half a
-ULP, and the bf16 ULP is up to ``2^-7`` relative (7 explicit mantissa
-bits), so each element moves by at most ``2^-8`` relative and a D-term
-f32 dot over rounded operands by at most ``2^-8 * sum|q_i c_i|``.  For
-the unit-scale data used across this suite that lands well inside a
-couple of bf16 ULPs at the score scale; 4 leaves deterministic headroom
-without ever excusing an f32-sized error.
+Bitwise equality (:func:`assert_topk_bitwise`) is kept only where one
+compiled program is compared with itself: a cached result vs its
+producer, a served row vs the offline batched run of the same pipeline,
+a property that holds exactly by construction (integer-valued data).
 
 Recall@k == 1.0 needs the oracle's top-k to be separated from rank k+1
 by more than the bf16 perturbation; :func:`planted_margin_corpus` builds
@@ -40,36 +22,44 @@ from __future__ import annotations
 import sys
 from pathlib import Path
 
-import jax.numpy as jnp
 import numpy as np
+
+from repro.core.precision import (BF16_MAX_ULP, F32_MAX_ULP,  # noqa: F401
+                                  bf16_contract_violations,
+                                  topk_close_violations)
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))  # for the
 # canonical margin-planted constructions in benchmarks/common.py
 
-# Score-error budget for the bf16 tier, in bf16 ULPs measured at each
-# row's score scale (max |oracle score| of the row).  Documented in
-# docs/ARCHITECTURE.md; the CI bf16 step enforces it on every backend.
-BF16_MAX_ULP = 4.0
-
-# bf16 has 8 total mantissa bits (7 explicit): one ULP at magnitude m is
-# 2**(floor(log2 m) - 7).
-_BF16_MANTISSA_BITS = 7
-
 
 def assert_topk_bitwise(want, got, ctx=""):
-    """f32-tier (and within-bf16-tier) contract: scores AND indices are
-    bit-identical."""
+    """One program against itself: scores AND indices are bit-identical."""
     np.testing.assert_array_equal(np.asarray(want.scores),
                                   np.asarray(got.scores), err_msg=str(ctx))
     np.testing.assert_array_equal(np.asarray(want.indices),
                                   np.asarray(got.indices), err_msg=str(ctx))
 
 
-def bf16_ulp_at(scale: np.ndarray) -> np.ndarray:
-    """One bf16 ULP at magnitude ``scale`` (elementwise; scale > 0)."""
-    scale = np.maximum(np.abs(np.asarray(scale, np.float64)),
-                       np.finfo(np.float32).tiny)
-    return 2.0 ** (np.floor(np.log2(scale)) - _BF16_MANTISSA_BITS)
+def assert_topk_close(want, got, *, max_ulp: float = F32_MAX_ULP, ctx=""):
+    """The f32 rule: scores within ``max_ulp`` f32 ULPs at the row
+    scale, ids equal outside near-tie groups (``want`` is the oracle)."""
+    bad = topk_close_violations(want, got, max_ulp=max_ulp)
+    assert not bad, f"{'; '.join(bad[:3])} {ctx}"
+
+
+def assert_scores_close(want, got, *, max_ulp: float = F32_MAX_ULP, ctx=""):
+    """The f32 rule on full score matrices [B, N]: every score within
+    ``max_ulp`` f32 ULPs at its row's scale."""
+    from repro.core.precision import f32_ulp_at
+
+    ws = np.asarray(want, np.float64)
+    gs = np.asarray(got, np.float64)
+    assert ws.shape == gs.shape, f"shape {gs.shape} != {ws.shape} {ctx}"
+    scale = np.max(np.abs(ws), axis=-1, keepdims=True)
+    err = np.abs(gs - ws) / f32_ulp_at(scale)
+    worst = float(err.max()) if err.size else 0.0
+    assert worst <= max_ulp, \
+        f"score error {worst:.1f} f32 ULP exceeds {max_ulp} {ctx}"
 
 
 def recall_at_k(oracle_indices, got_indices) -> float:
@@ -86,28 +76,11 @@ def recall_at_k(oracle_indices, got_indices) -> float:
 
 def assert_bf16_oracle_contract(oracle, got, *, max_ulp: float = BF16_MAX_ULP,
                                 ctx=""):
-    """Cross-tier contract: a bf16-tier result vs the f32 oracle on the
-    ORIGINAL corpus must have recall@k == 1.0 and per-row score error
-    within ``max_ulp`` bf16 ULPs at the oracle's row score scale.
-
-    Scores are compared rank-to-rank: with the index sets equal, the
-    j-th largest bf16 score and j-th largest f32 score differ by at most
-    the largest single-document perturbation, even when near-ties swap
-    ranks inside the set."""
-    rec = recall_at_k(oracle.indices, got.indices)
-    assert rec == 1.0, f"recall@k vs f32 oracle = {rec} != 1.0 {ctx}"
-    o = np.asarray(oracle.scores, np.float64)
-    g = np.asarray(got.scores, np.float64)
-    finite = np.isfinite(o) & np.isfinite(g)      # k > n_valid tails
-    np.testing.assert_array_equal(np.isfinite(o), np.isfinite(g),
-                                  err_msg=f"-inf tails must align {ctx}")
-    scale = np.max(np.where(finite, np.abs(o), 0.0), axis=1, keepdims=True)
-    o_f = np.where(finite, o, 0.0)                # keep inf - inf out of
-    g_f = np.where(finite, g, 0.0)                # the subtraction
-    err_ulp = np.abs(g_f - o_f) / bf16_ulp_at(scale)
-    worst = float(err_ulp.max()) if err_ulp.size else 0.0
-    assert worst <= max_ulp, \
-        f"bf16 score error {worst:.2f} ULP exceeds bound {max_ulp} {ctx}"
+    """The cross-tier rule: recall@k == 1.0 against the f32 oracle on
+    the ORIGINAL corpus, per-rank score error within ``max_ulp`` bf16
+    ULPs at the oracle's row scale."""
+    bad = bf16_contract_violations(oracle, got, max_ulp=max_ulp)
+    assert not bad, f"{'; '.join(bad)} {ctx}"
 
 
 def planted_margin_corpus(n: int, d: int, b: int, k: int, *, seed: int = 0):

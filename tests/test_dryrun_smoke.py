@@ -23,7 +23,7 @@ cell = build_cell("{arch}", "{shape}", mesh)
 with mesh:
     compiled = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                        donate_argnums=cell.donate).lower(*cell.args).compile()
-cost = RL.cost_dict(compiled)
+cost = compiled.cost_analysis()
 assert float(cost.get("flops", 0)) > 0
 coll = RL.collective_bytes_from_hlo(compiled.as_text())
 roof = RL.analyze_terms(float(cost["flops"]),

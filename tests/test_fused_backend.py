@@ -1,11 +1,12 @@
 """Fused-space execution backends: mixed dense+sparse retrieval selects
-on-device, bit-identically.
+on-device, within the stated f32 bound.
 
-The contract under test (PR 4): for ``FusedSpace``/``SparseSpace``
-corpora, ``reference`` (one-shot exact_topk), ``streaming`` (pytree tile
-scan), and ``pallas`` (the one-pass fused score+select kernel
-``kernels/fused_topk.py``, interpret mode on CPU) return **bit-identical
-f32 scores and indices** across eager/jit/scan contexts; ``resolve_
+The contract under test: for ``FusedSpace``/``SparseSpace`` corpora,
+``reference`` (one-shot exact_topk), ``streaming`` (pytree tile scan),
+and ``pallas`` (the one-pass fused score+select kernel
+``kernels/fused_topk.py``, interpret mode on CPU) return the same ids
+and f32 scores within the bound of ``tests/_precision.py`` across
+eager/jit/scan contexts; ``resolve_
 backend`` stops falling back to reference for fused corpora (``"auto"``
 picks the kernel for large ones); learned ``w_dense``/``w_sparse``
 weights thread from ``core.fusion`` through the backend seam; and
@@ -26,12 +27,14 @@ from repro.core.backends import (AUTO_PALLAS_MIN_ROWS, PallasBackend,
                                  ReferenceBackend, StreamingBackend,
                                  auto_tile_n, legal_tile, make_backend,
                                  resolve_backend)
+from repro.core.brute_force import TopK
 from repro.core.fusion import learn_fused_weights
 from repro.core.pipeline import BruteForceGenerator, RetrievalPipeline
 from repro.core.sparse import SparseVectors, from_dense
 from repro.core.spaces import DenseSpace, FusedSpace, FusedVectors, SparseSpace
 from repro.kernels import ops, ref
 from repro.serving import RetrievalService
+from _precision import assert_scores_close, assert_topk_close
 
 pytestmark = pytest.mark.fused
 
@@ -60,6 +63,7 @@ def _fused_setup(n, v, nnz, dd, b, seed=0):
 
 
 def assert_topk_equal(want, got, ctx=""):
+    """One program against itself: bit for bit."""
     np.testing.assert_array_equal(np.asarray(want.scores),
                                   np.asarray(got.scores), err_msg=str(ctx))
     np.testing.assert_array_equal(np.asarray(want.indices),
@@ -78,11 +82,10 @@ class TestKernelVsOracle:
         got = ops.fused_topk(queries.sparse, queries.dense, corpus.sparse,
                              corpus.dense, v, k, w_dense=wd, w_sparse=ws,
                              tile_n=tile)
-        want_s, want_i = ref.fused_topk_ref(
+        want = ref.fused_topk_ref(
             queries.sparse, queries.dense, corpus.sparse, corpus.dense, v, k,
             w_dense=wd, w_sparse=ws)
-        assert np.array_equal(np.asarray(got.scores), np.asarray(want_s))
-        assert np.array_equal(np.asarray(got.indices), np.asarray(want_i))
+        assert_topk_close(TopK(*want), got, ctx=(n, wd, ws))
 
     def test_l2_dense_component(self):
         """The kernel's l2 branch matches the oracle (kernel-level only:
@@ -93,11 +96,10 @@ class TestKernelVsOracle:
         got = ops.fused_topk(queries.sparse, queries.dense, corpus.sparse,
                              corpus.dense, v, 6, w_dense=0.7, w_sparse=0.3,
                              dense_kind="l2", tile_n=64)
-        want_s, want_i = ref.fused_topk_ref(
+        want = ref.fused_topk_ref(
             queries.sparse, queries.dense, corpus.sparse, corpus.dense, v, 6,
             w_dense=0.7, w_sparse=0.3, dense_kind="l2")
-        assert np.array_equal(np.asarray(got.scores), np.asarray(want_s))
-        assert np.array_equal(np.asarray(got.indices), np.asarray(want_i))
+        assert_topk_close(TopK(*want), got)
 
     def test_single_component_calls(self):
         v = 50
@@ -105,17 +107,15 @@ class TestKernelVsOracle:
         # sparse-only, unscaled (SparseSpace semantics)
         got = ops.fused_topk(queries.sparse, None, corpus.sparse, None, v, 5,
                              tile_n=128)
-        want_s, want_i = ref.fused_topk_ref(queries.sparse, None,
-                                            corpus.sparse, None, v, 5)
-        assert np.array_equal(np.asarray(got.scores), np.asarray(want_s))
-        assert np.array_equal(np.asarray(got.indices), np.asarray(want_i))
+        want = ref.fused_topk_ref(queries.sparse, None, corpus.sparse, None,
+                                  v, 5)
+        assert_topk_close(TopK(*want), got)
         # dense-only with a baked weight
         got = ops.fused_topk(None, queries.dense, None, corpus.dense, 0, 5,
                              w_dense=0.7, tile_n=64)
-        want_s, want_i = ref.fused_topk_ref(None, queries.dense, None,
-                                            corpus.dense, 0, 5, w_dense=0.7)
-        assert np.array_equal(np.asarray(got.scores), np.asarray(want_s))
-        assert np.array_equal(np.asarray(got.indices), np.asarray(want_i))
+        want = ref.fused_topk_ref(None, queries.dense, None, corpus.dense,
+                                  0, 5, w_dense=0.7)
+        assert_topk_close(TopK(*want), got)
 
     def test_no_components_raises(self):
         with pytest.raises(ValueError, match="no overlapping components"):
@@ -136,21 +136,20 @@ class TestKernelVsOracle:
                                corpus.dense, v, 5)
 
     def test_fused_scores_bit_identical_to_space(self):
-        """Regression: the score-only kernel (ops.fused_scores) must stay
-        a bit-identical drop-in for FusedSpace.score_batch after the
-        weighted_mix arithmetic change."""
+        """Regression: the score-only kernel (ops.fused_scores) stays a
+        drop-in for FusedSpace.score_batch, within the f32 bound."""
         v = 50
         corpus, queries = _fused_setup(300, v, 8, 16, 3)
         space = FusedSpace(v, w_dense=0.6, w_sparse=0.4)
         want = space.score_batch(queries, corpus)
         got = ops.fused_scores(queries.sparse, queries.dense, corpus.sparse,
                                corpus.dense, v, 0.6, 0.4, tile_n=64)
-        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+        assert_scores_close(want, got)
 
 
 class TestParity:
-    """reference == streaming == pallas-interpret, bit-for-bit f32, for
-    fused and pure-sparse corpora."""
+    """reference == streaming == pallas-interpret, within the f32 bound,
+    for fused and pure-sparse corpora."""
 
     @pytest.mark.parametrize("wd,ws", WEIGHTS)
     @pytest.mark.parametrize("n,dd,nnz,b,k,tile", SHAPES)
@@ -162,7 +161,7 @@ class TestParity:
         space = FusedSpace(v, w_dense=wd, w_sparse=ws)
         want = ReferenceBackend().topk(space, queries, corpus, k)
         got = make_backend(name, tile_n=tile).topk(space, queries, corpus, k)
-        assert_topk_equal(want, got, (name, n, wd, ws))
+        assert_topk_close(want, got, ctx=(name, n, wd, ws))
 
     @pytest.mark.parametrize("name", BACKENDS[1:])
     def test_sparse_space_bit_identical(self, name):
@@ -174,7 +173,7 @@ class TestParity:
         want = ReferenceBackend().topk(space, queries.sparse, corpus.sparse, 9)
         got = make_backend(name, tile_n=64).topk(space, queries.sparse,
                                                  corpus.sparse, 9)
-        assert_topk_equal(want, got, name)
+        assert_topk_close(want, got, ctx=name)
 
     @pytest.mark.parametrize("name", BACKENDS[1:])
     def test_partial_components_match_reference(self, name):
@@ -189,7 +188,7 @@ class TestParity:
                       FusedVectors(corpus.dense, None))]:
             want = ReferenceBackend().topk(space, q, c, 6)
             got = make_backend(name, tile_n=64).topk(space, q, c, 6)
-            assert_topk_equal(want, got, name)
+            assert_topk_close(want, got, ctx=name)
 
     @pytest.mark.parametrize("name", BACKENDS[1:])
     def test_tie_break_matches_reference(self, name):
@@ -204,7 +203,11 @@ class TestParity:
         space = FusedSpace(v, w_dense=0.5, w_sparse=0.5)
         want = ReferenceBackend().topk(space, queries, corpus, 24)
         got = make_backend(name, tile_n=32).topk(space, queries, corpus, 24)
-        assert_topk_equal(want, got, name)
+        assert_topk_close(want, got, ctx=name)
+        # duplicates score identically within one path: ties go to the
+        # lower row id at every rank
+        np.testing.assert_array_equal(np.asarray(want.indices),
+                                      np.asarray(got.indices))
 
     @pytest.mark.parametrize("name", BACKENDS)
     def test_n_valid_masks_padding_rows(self, name):
@@ -222,7 +225,7 @@ class TestParity:
             space, queries, padded, 8, n_valid=128)
         assert np.asarray(got.indices).max() < 128
         want = ReferenceBackend().topk(space, queries, corpus, 8)
-        assert_topk_equal(want, got, name)
+        assert_topk_close(want, got, ctx=name)
 
     @pytest.mark.parametrize("n_valid", [0, 4])
     @pytest.mark.parametrize("name", BACKENDS)
@@ -253,11 +256,12 @@ class TestParity:
             fn = jax.jit(lambda qq: backend.topk(space, qq, corpus, 10))
             outs.append(fn(queries))
         for got in outs[1:]:
-            assert_topk_equal(outs[0], got)
+            assert_topk_close(outs[0], got)
 
     def test_parity_jit_vs_eager(self):
         """With the corpus as a jit ARGUMENT (no constant folding), jitted
-        results equal eager results bit for bit on every backend."""
+        results equal eager results within the f32 bound on every
+        backend (XLA may fuse the jitted graph differently)."""
         v = 50
         corpus, queries = _fused_setup(300, v, 8, 16, 4)
         space = FusedSpace(v, w_dense=0.6, w_sparse=0.4)
@@ -266,17 +270,17 @@ class TestParity:
             eager = backend.topk(space, queries, corpus, 10)
             jitted = jax.jit(lambda q, c: backend.topk(space, q, c, 10))(
                 queries, corpus)
-            assert_topk_equal(eager, jitted, name)
+            assert_topk_close(eager, jitted, ctx=name)
 
     def test_auto_tiled_kernel_matches_fixed_tile(self):
-        """tile_n=None auto-tunes; answers are bit-identical at any
-        tile."""
+        """tile_n=None auto-tunes; answers agree (within the f32 bound)
+        at any tile."""
         v = 50
         corpus, queries = _fused_setup(300, v, 8, 16, 4)
         space = FusedSpace(v, w_dense=0.6, w_sparse=0.4)
         fixed = PallasBackend(tile_n=64).topk(space, queries, corpus, 10)
         auto = PallasBackend().topk(space, queries, corpus, 10)
-        assert_topk_equal(fixed, auto)
+        assert_topk_close(fixed, auto)
 
 
 class TestPaddedCOOInvariants:
@@ -286,8 +290,8 @@ class TestPaddedCOOInvariants:
     @given(st.integers(0, 2**31 - 1))
     def test_extra_pad_slots_are_inert(self, seed):
         """Appending pad slots (id == V, value 0) to every corpus row must
-        not change scores or selected ids: pad ids land in the densified
-        query table's zero trash column."""
+        not change scores or selected ids: the pad id matches no query
+        term."""
         v = 40
         corpus, queries = _fused_setup(128, v, 6, 8, 3, seed=seed % 997)
         space = FusedSpace(v, w_dense=0.5, w_sparse=0.5)
@@ -401,7 +405,7 @@ class TestResolution:
         want = ReferenceBackend().topk(learned, queries, corpus, 10)
         got = resolve_backend("pallas", learned, corpus).topk(
             learned, queries, corpus, 10)
-        assert_topk_equal(want, got)
+        assert_topk_close(want, got)
         # and the learned weights actually reach the scores: a different
         # mix must produce different top-1 scores somewhere
         other = ReferenceBackend().topk(space.with_weights(ws, wd),
@@ -420,11 +424,11 @@ class TestResolution:
         want = gen.generate(queries, 10)
         for name in BACKENDS:
             got = gen.with_backend(name).generate(queries, 10)
-            assert_topk_equal(want, got, name)
+            assert_topk_close(want, got, ctx=name)
         rebound = RetrievalPipeline(gen, cand_qty=10,
                                     final_qty=10).with_backend("pallas")
         assert isinstance(rebound.backend, PallasBackend)
-        assert_topk_equal(want, rebound.run(queries))
+        assert_topk_close(want, rebound.run(queries))
 
 
 class TestAutoTile:
@@ -469,8 +473,9 @@ class TestAutoTile:
 class TestServedFused:
     def test_fused_endpoint_pair_parity_under_load(self):
         """One fused corpus behind two endpoints differing only in
-        backend= — bit-identical answers through the batcher under
-        concurrent load, kernel identity in the stats snapshot."""
+        backend= — the same answers (within the f32 bound) through the
+        batcher under concurrent load, kernel identity in the stats
+        snapshot."""
         v = 50
         corpus, queries = _fused_setup(300, v, 8, 16, 40, seed=7)
         space = FusedSpace(v, w_dense=0.6, w_sparse=0.4)
@@ -486,14 +491,15 @@ class TestServedFused:
             futs_ref = [svc.submit(one(i), endpoint="ref") for i in range(40)]
             futs_pal = [svc.submit(one(i), endpoint="pal") for i in range(40)]
             for a, b in zip(futs_ref, futs_pal):
-                ra, rb = a.result(), b.result()
-                assert np.array_equal(ra.scores, rb.scores)
-                assert np.array_equal(ra.indices, rb.indices)
+                assert_topk_close(a.result(), b.result())
             snap = svc.snapshot()
         assert snap.endpoints["ref"].backend == "reference"
         assert snap.endpoints["pal"].backend.startswith("pallas(")
         # served results equal the offline run too
         off = pipe.run(queries)
         assert np.array_equal(
-            np.stack([f.result().indices for f in futs_pal]),
+            np.stack([f.result().indices for f in futs_ref]),
             np.asarray(off.indices))
+        served = [f.result() for f in futs_pal]
+        assert_topk_close(off, TopK(np.stack([r.scores for r in served]),
+                                    np.stack([r.indices for r in served])))

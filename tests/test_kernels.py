@@ -11,7 +11,9 @@ except ImportError:          # bare install: seeded parametrized fallback
     from _proptest import given, settings, st
 
 from repro.core.sparse import from_dense, densify
+from repro.core.brute_force import TopK
 from repro.kernels import ops, ref
+from _precision import assert_topk_close
 
 
 @pytest.mark.parametrize("b,n,d,k,tile", [
@@ -22,17 +24,16 @@ from repro.kernels import ops, ref
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_mips_topk_vs_oracle(b, n, d, k, tile, dtype):
-    """Exact for BOTH dtypes since the precision contract: the library
-    oracle upcasts to f32 before the first multiply exactly like the
-    kernel's per-tile upcast, so bf16 inputs no longer need a tolerance
-    band — kernel and oracle are bitwise equal per corpus dtype."""
+    """The f32 rule of ``tests/_precision.py`` for BOTH dtypes: the
+    library oracle upcasts to f32 before the first multiply like the
+    kernel's per-tile upcast, so a bf16 corpus needs no wider band —
+    only the summation order differs."""
     q = jax.random.normal(jax.random.PRNGKey(0), (b, d), dtype)
     c = jax.random.normal(jax.random.PRNGKey(1), (n, d), dtype)
     got = ops.mips_topk(q, c, k, tile_n=tile)
-    want_s, want_i = ref.mips_topk_ref(q, c, k)
-    assert str(got.scores.dtype) == str(want_s.dtype) == "float32"
-    assert np.array_equal(np.asarray(got.scores), np.asarray(want_s))
-    assert np.array_equal(np.asarray(got.indices), np.asarray(want_i))
+    want = TopK(*ref.mips_topk_ref(q, c, k))
+    assert str(got.scores.dtype) == str(want.scores.dtype) == "float32"
+    assert_topk_close(want, got)
 
 
 @pytest.mark.parametrize("space", ["ip", "l2"])

@@ -19,6 +19,7 @@ from repro.core.spaces import DenseSpace, FusedSpace, FusedVectors
 from repro.distributed import ParallelCtx
 from repro.distributed.mesh_utils import local_mesh
 from repro.serving import RetrievalService, ShardedPipeline, shard_corpus
+from _precision import assert_topk_close
 
 N_DOCS, DIM, VOCAB, NNZ = 257, 16, 64, 8   # odd N: uneven shard splits
 
@@ -282,9 +283,9 @@ class TestServedSharded:
     @pytest.mark.fused
     @pytest.mark.parametrize("backend", ["streaming", "pallas"])
     def test_fused_sharded_backend_offline(self, fused_data, backend):
-        """PR 4: per-shard fused generators on the kernel/tiled paths —
-        the K-shard merge stays bit-identical to the unsharded reference
-        scan (shard slices are just smaller fused corpora)."""
+        """Per-shard fused generators on the kernel/tiled paths — the
+        K-shard merge matches the unsharded reference scan within the f32
+        bound (shard slices are just smaller fused corpora)."""
         corpus, queries = fused_data
         space = FusedSpace(VOCAB, w_dense=0.6, w_sparse=0.4)
         base = RetrievalPipeline(BruteForceGenerator(space, corpus),
@@ -296,14 +297,15 @@ class TestServedSharded:
             assert not any(isinstance(g.backend, ReferenceBackend)
                            for g in sharded.generators), \
                 "fused shards must resolve to the requested backend"
-            assert_topk_equal(sharded.run(queries), base.run(queries))
+            assert_topk_close(base.run(queries), sharded.run(queries))
 
     @pytest.mark.fused
     def test_fused_sharded_pallas_endpoint_under_concurrent_load(
             self, fused_data):
         """Satellite acceptance: the fused endpoint on the pallas backend,
-        served K=2-sharded, answers bit-identically to the unsharded
-        reference endpoint while several client threads hammer both."""
+        served K=2-sharded, answers as the unsharded reference endpoint
+        does (within the f32 bound) while several client threads hammer
+        both."""
         corpus, queries = fused_data
         space = FusedSpace(VOCAB, w_dense=0.5, w_sparse=0.5)
         base = RetrievalPipeline(BruteForceGenerator(space, corpus),
@@ -342,11 +344,12 @@ class TestServedSharded:
         assert snap.endpoints["sharded_pallas"].backend.startswith("pallas(")
         off = base.run(queries)
         for i in range(n):
-            for ep in ("flat", "sharded_pallas"):
-                np.testing.assert_array_equal(
-                    results[ep][i].scores, np.asarray(off.scores)[i])
-                np.testing.assert_array_equal(
-                    results[ep][i].indices, np.asarray(off.indices)[i])
+            np.testing.assert_array_equal(
+                results["flat"][i].scores, np.asarray(off.scores)[i])
+            np.testing.assert_array_equal(
+                results["flat"][i].indices, np.asarray(off.indices)[i])
+            assert_topk_close(TopK(off.scores[i], off.indices[i]),
+                              results["sharded_pallas"][i], ctx=i)
 
 
 class TestConcatTopk:
