@@ -326,6 +326,7 @@ def beam_hop_pallas(qdensified, q_dense, beam_s, beam_i, visited, neighbors,
         has_dense=has_dense, has_sparse=has_sparse)
     return pallas_call(
         kernel,
+        name="beam_topk",
         grid=(b // qb,),
         in_specs=in_specs,
         out_specs=[

@@ -220,6 +220,7 @@ def fused_topk_pallas(q_ids, q_w, q_dense, c_idx, c_val, c_dense, k: int,
         dense_kind=dense_kind, has_dense=has_dense, has_sparse=has_sparse)
     out_s, out_i = pallas_call(
         kernel,
+        name="fused_topk",
         grid=(n_tiles,),
         in_specs=in_specs,
         out_specs=[

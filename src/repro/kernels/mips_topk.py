@@ -126,6 +126,7 @@ def mips_topk_pallas(queries: jax.Array, corpus: jax.Array, k: int,
                                n_valid=n_valid, space=space)
     out_s, out_i = pallas_call(
         kernel,
+        name="mips_topk",
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((b, d), lambda t: (0, 0)),          # queries resident

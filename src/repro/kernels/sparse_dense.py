@@ -53,6 +53,7 @@ def fused_score_pallas(q_ids: jax.Array, q_w: jax.Array, q_dense: jax.Array,
     kernel = functools.partial(_kernel, w_dense=w_dense, w_sparse=w_sparse)
     return pallas_call(
         kernel,
+        name="sparse_dense",
         grid=(n // tile_n,),
         in_specs=[
             pl.BlockSpec((bt, 1), lambda t: (0, 0)),

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.serving.stats import ServingStats
+from repro.serving.stats import ServingStats, span
 
 __all__ = ["Request", "ContinuousBatcher", "ServiceOverloaded",
            "OVERLOAD_POLICIES"]
@@ -228,30 +228,44 @@ class ContinuousBatcher:
 
     # -- worker side --------------------------------------------------------
     def _loop(self):
+        seq = 0          # this endpoint's batches: the spans' ``batch``
         while not self._stop.is_set():
-            batch, closed_by = self._gather()
+            phases: dict = {}
+            batch, closed_by = self._gather(seq, phases)
             if batch:
-                self._safe_execute(batch, closed_by)
+                self._safe_execute(batch, closed_by, seq, phases)
+                seq += 1
         # drain: everything still queued is flushed in fixed-size batches
         leftover = self._queue.drain()
         for i in range(0, len(leftover), self.batch_size):
-            self._safe_execute(leftover[i:i + self.batch_size], "drain")
+            self._safe_execute(leftover[i:i + self.batch_size], "drain",
+                               seq, {})
+            seq += 1
 
-    def _safe_execute(self, batch: List[Request], closed_by: str):
+    def _safe_execute(self, batch: List[Request], closed_by: str, seq: int,
+                      phases: dict):
         """The worker must survive anything a batch throws at it."""
         try:
-            self._execute(batch, closed_by)
+            self._execute(batch, closed_by, seq, phases)
         except Exception as exc:            # noqa: BLE001
             for r in batch:
                 if not r.future.done():
                     r.future.set_exception(exc)
 
-    def _gather(self):
-        """Block for the first request, then fill until size or deadline."""
+    def _gather(self, seq: int, phases: dict):
+        """Block for the first request, then fill until size or deadline.
+        The ``serve.gather`` span (and phase) starts at the first request:
+        the idle polling before it is not the batch's."""
         first = self._queue.get(timeout=_POLL_S)
         if first is None:
             return [], None
-        batch = [first]
+        with span("serve.gather", phases, endpoint=self.name,
+                  batch=seq) as sp:
+            batch, closed_by = self._fill([first])
+            sp.set(closed_by=closed_by, n=len(batch))
+        return batch, closed_by
+
+    def _fill(self, batch: List[Request]):
         deadline = self._time_fn() + self.max_wait_s
         while len(batch) < self.batch_size:
             if self._stop.is_set():
@@ -274,41 +288,58 @@ class ContinuousBatcher:
         toks = [r.q_tokens for r in batch] + [self.pad_q_tokens] * n_pad
         return stacked, jax.tree.map(lambda *xs: jnp.stack(xs), *toks)
 
-    def _execute(self, batch: List[Request], closed_by: str):
-        t0 = self._time_fn()
-        try:
-            stacked, tokens = self._assemble(batch)
-            if getattr(self.run_fn, "budget_aware", False):
-                # budget-aware runners (the served funnel) get the time
-                # this batch already spent queued — enforcement starts
-                # at batch close, so an end-to-end budget covers the
-                # request's whole life, not just compute
-                elapsed = max(t0 - min(r.t_admit for r in batch), 0.0)
-                out = self.run_fn(stacked, tokens, elapsed_s=elapsed)
-            else:
-                out = self.run_fn(stacked, tokens)
-            out = jax.tree.map(
-                lambda x: np.asarray(jax.block_until_ready(x)), out)
-        except Exception as exc:            # noqa: BLE001 — fan out to futures
-            for r in batch:
-                if not r.future.done():
-                    r.future.set_exception(exc)
-            return
-        t1 = self._time_fn()
-        self.stats.record_batch(
-            self.name, served=len(batch), capacity=self.batch_size,
-            closed_by=closed_by,
-            queue_waits_s=[t0 - r.t_admit for r in batch],
-            exec_s=t1 - t0)
-        for i, r in enumerate(batch):
-            result = jax.tree.map(lambda x: x[i], out)
-            if self.on_result is not None:
-                self.on_result(r, result)
-            self.stats.record_e2e(self.name, self._time_fn() - r.t_admit)
-            # a client may have cancelled the future while it was queued;
-            # claiming it as running makes set_result race-free
-            if r.future.set_running_or_notify_cancel():
-                r.future.set_result(result)
+    def _execute(self, batch: List[Request], closed_by: str, seq: int,
+                 phases: dict):
+        """One batch, under the ``serve.batch`` span: the ``serve.assemble``,
+        ``serve.dispatch``, ``serve.sync`` and ``serve.copy_back`` phases
+        between ``t0`` and ``t1`` (``exec_s``), then ``serve.fanout``."""
+        with span("serve.batch", endpoint=self.name, batch=seq,
+                  served=len(batch)):
+            t0 = self._time_fn()
+            try:
+                with span("serve.assemble", phases):
+                    stacked, tokens = self._assemble(batch)
+                with span("serve.dispatch", phases):
+                    if getattr(self.run_fn, "budget_aware", False):
+                        # budget-aware runners (the served funnel) get the
+                        # time this batch already spent queued —
+                        # enforcement starts at batch close, so an
+                        # end-to-end budget covers the request's whole
+                        # life, not just compute
+                        elapsed = max(t0 - min(r.t_admit for r in batch),
+                                      0.0)
+                        out = self.run_fn(stacked, tokens, elapsed_s=elapsed)
+                    else:
+                        out = self.run_fn(stacked, tokens)
+                with span("serve.sync", phases):
+                    jax.block_until_ready(out)
+                with span("serve.copy_back", phases):
+                    out = jax.tree.map(np.asarray, out)
+            except Exception as exc:        # noqa: BLE001 — fan out to futures
+                for r in batch:
+                    if not r.future.done():
+                        r.future.set_exception(exc)
+                return
+            t1 = self._time_fn()
+            self.stats.record_batch(
+                self.name, served=len(batch), capacity=self.batch_size,
+                closed_by=closed_by,
+                queue_waits_s=[t0 - r.t_admit for r in batch],
+                exec_s=t1 - t0, phases=phases)
+            fanout: dict = {}
+            with span("serve.fanout", fanout):
+                for i, r in enumerate(batch):
+                    result = jax.tree.map(lambda x: x[i], out)
+                    if self.on_result is not None:
+                        self.on_result(r, result)
+                    self.stats.record_e2e(self.name,
+                                          self._time_fn() - r.t_admit)
+                    # a client may have cancelled the future while it was
+                    # queued; claiming it as running makes set_result
+                    # race-free
+                    if r.future.set_running_or_notify_cancel():
+                        r.future.set_result(result)
+            self.stats.record_fanout(self.name, fanout["fanout"])
 
     def close(self):
         """Stop accepting (wakes blocked submitters), flush the queue, join

@@ -28,7 +28,9 @@ so for exact generators the sharded result equals the unsharded
 the f32 rule of ``core.precision`` on the kernel and tiled backends,
 whose sum order depends on the shard's shape — ``tests/test_sharded.py``).
 With a mesh, the per-shard ``B x k`` lists move to the first shard's
-device for the merge.
+device for the merge.  Each shard's call runs under a ``shard.scan``
+profiler span (attribute ``shard``) on its own thread, and the move and
+merge under ``shard.merge`` on the calling one.
 
 A ``ShardedPipeline`` exposes ``run(query_repr, q_tokens)`` and
 ``generate(query_repr, k)``, so it registers behind a single
@@ -51,6 +53,7 @@ from repro.core.brute_force import TopK, concat_topk, merge_topk
 from repro.core.pipeline import (BruteForceGenerator, apply_rerankers,
                                  pin_snapshot)
 from repro.core.spaces import canonical_dtype, cast_corpus
+from repro.serving.stats import span
 
 __all__ = ["CorpusShard", "shard_corpus", "ShardedPipeline"]
 
@@ -264,8 +267,9 @@ class ShardedPipeline:
         # RetrievalPipeline and the serving funnel).
         generators = [pin_snapshot(g) for g in self.generators]
 
-        def one(gen, shard: CorpusShard) -> TopK:
-            local = gen.generate(query_repr, min(k, shard.n_rows))
+        def one(i: int, gen, shard: CorpusShard) -> TopK:
+            with span("shard.scan", shard=i):
+                local = gen.generate(query_repr, min(k, shard.n_rows))
             return TopK(local.scores, local.indices + shard.offset)
 
         # under a jit trace the queries are tracers, which must not cross
@@ -273,14 +277,16 @@ class ShardedPipeline:
         # "parallel" shard-by-shard in the compiled graph anyway
         tracing = any(isinstance(leaf, jax.core.Tracer)
                       for leaf in jax.tree.leaves(query_repr))
+        ids = range(self.n_shards)
         if self.executor is not None and not tracing:
-            parts = list(self.executor.map(one, generators, self.shards))
+            parts = list(self.executor.map(one, ids, generators, self.shards))
         else:
-            parts = [one(g, s) for g, s in zip(generators, self.shards)]
-        if not tracing:
-            parts = _on_one_device(parts)
-        cat = concat_topk(parts)
-        return merge_topk(cat, min(k, cat.scores.shape[1]))
+            parts = list(map(one, ids, generators, self.shards))
+        with span("shard.merge"):
+            if not tracing:
+                parts = _on_one_device(parts)
+            cat = concat_topk(parts)
+            return merge_topk(cat, min(k, cat.scores.shape[1]))
 
     def run(self, query_repr, q_tokens=None) -> TopK:
         cands = self.generate(query_repr, self.cand_qty)

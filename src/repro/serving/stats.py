@@ -18,6 +18,15 @@ Endpoints registered with an execution backend also surface its identity
 string in snapshots, so a latency regression can be attributed to the
 path (reference / streaming / pallas) actually serving the endpoint.
 
+Batch phases: :class:`span` marks a phase of the serving path in the
+profiler's trace (``jax.profiler.TraceAnnotation``, on the clock of the
+device planes) and adds its host-clock duration to a per-batch dict; the
+batcher hands that dict to :meth:`ServingStats.record_batch` (and the
+fan-out, which ends as the futures resolve, to
+:meth:`ServingStats.record_fanout`), and ``EndpointSnapshot.phase_total_s``
+carries the exact lifetime totals of the six phases (``PHASES``), the
+split a trace shows, without a profiler.
+
 All recorders are thread-safe: requests are admitted from client threads
 while batcher worker threads record execution.
 """
@@ -31,11 +40,48 @@ import time
 from typing import Callable, Dict, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = ["LatencySummary", "EndpointSnapshot", "ServiceSnapshot",
-           "ServingStats"]
+           "ServingStats", "span", "PHASES"]
+
+# the batcher's phases, in the order a batch passes through them
+PHASES = ("gather", "assemble", "dispatch", "sync", "copy_back", "fanout")
 
 _RESERVOIR = 8192
+
+
+class span:
+    """One phase of the serving path: a profiler span named ``name``
+    (``"serve.gather"``) carrying ``attrs``, and, when ``phases`` (a
+    per-batch dict) is given, the phase's host-clock seconds added to it
+    under the name's last part (``"gather"``).
+
+    With no profiler session it costs the annotation's enter and exit and
+    two ``time.monotonic`` reads.  :meth:`set` adds attributes known only
+    at the end (how a batch closed)."""
+
+    __slots__ = ("_ann", "_phases", "_key", "_t")
+
+    def __init__(self, name: str, phases: Optional[Dict[str, float]] = None,
+                 **attrs):
+        self._ann = TraceAnnotation(name, **attrs)
+        self._phases = phases
+        self._key = name.rpartition(".")[2]
+
+    def set(self, **attrs):
+        self._ann.set_metadata(**attrs)
+
+    def __enter__(self) -> "span":
+        self._t = time.monotonic()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        if self._phases is not None:
+            self._phases[self._key] = (self._phases.get(self._key, 0.0)
+                                       + time.monotonic() - self._t)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,6 +122,12 @@ class EndpointSnapshot:
     # exact lifetime sums (the percentile reservoirs are bounded)
     queue_wait_total_s: float = 0.0
     execute_total_s: float = 0.0
+    # exact lifetime seconds of each batcher phase (``PHASES``): the
+    # worker's gather from the first request taken to batch close, then
+    # assembly, dispatch, device sync and copy back (together
+    # ``execute_total_s``), then the fan-out to the requests' futures
+    phase_total_s: Dict[str, float] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(PHASES, 0.0))
     # admission control (exact lifetime counters)
     depth_limit: Optional[int] = None   # None = unbounded queue
     rejected: int = 0               # submits refused under policy "reject"
@@ -91,13 +143,6 @@ class EndpointSnapshot:
     # register_pipeline(profile=...) / register_runner(profile=...) —
     # provenance for every number above (None = hand-configured)
     profile: Optional[str] = None
-    # process-wide warm-cache counters at snapshot time ({size, hits,
-    # misses}): the pallas tile auto-tune cache and the ANN index LRU.
-    # Shared across endpoints (the caches are module-level), surfaced
-    # here so the autotuner — and operators — can tell a warm
-    # measurement from one paying cold builds/tuning sweeps.
-    tile_cache: Optional[Dict[str, int]] = None
-    ann_index_cache: Optional[Dict[str, int]] = None
     # live-corpus freshness (None on frozen endpoints): the snapshot
     # generation currently served, per-segment row counts
     # ({"main": ..., "append": ...}), resident tombstoned rows, lifetime
@@ -155,6 +200,7 @@ class _EndpointStats:
         self.e2e = collections.deque(maxlen=_RESERVOIR)
         self.queue_wait_total_s = 0.0
         self.execute_total_s = 0.0
+        self.phase_total_s = dict.fromkeys(PHASES, 0.0)
         self.overload = collections.Counter()   # "rejected" / "shed"
         # staged-funnel recorders, keyed by stage name ("candgen" /
         # "fusion" / "rerank"): latency reservoirs, exact execution /
@@ -233,7 +279,10 @@ class ServingStats:
                 self.cache_misses += 1
 
     def record_batch(self, endpoint: str, *, served: int, capacity: int,
-                     closed_by: str, queue_waits_s, exec_s: float):
+                     closed_by: str, queue_waits_s, exec_s: float,
+                     phases: Optional[Dict[str, float]] = None):
+        """One executed batch; ``phases`` holds the seconds of the phases
+        it has passed so far (``span``'s per-batch dict)."""
         with self._lock:
             ep = self._ep(endpoint)
             ep.n_batches += 1
@@ -243,6 +292,15 @@ class ServingStats:
             ep.execute.append(exec_s)
             ep.queue_wait_total_s += sum(queue_waits_s)
             ep.execute_total_s += exec_s
+            for phase, seconds in (phases or {}).items():
+                ep.phase_total_s[phase] += seconds
+
+    def record_fanout(self, endpoint: str, seconds: float):
+        """The fan-out of a batch already recorded: it ends when the last
+        future resolves, after ``record_batch`` (whose counts a client
+        reading its result must already see)."""
+        with self._lock:
+            self._ep(endpoint).phase_total_s["fanout"] += seconds
 
     def record_e2e(self, endpoint: str, seconds: float):
         with self._lock:
@@ -276,14 +334,7 @@ class ServingStats:
 
     # -- read path ----------------------------------------------------------
     def snapshot(self) -> ServiceSnapshot:
-        # outside the lock: the warm-cache counters have their own locks,
-        # and backends is a lazy import so stats stays numpy-only until a
-        # snapshot is actually taken
-        from repro.core.backends import ann_index_cache_info, tile_cache_info
-
-        tile_cache = tile_cache_info()
-        ann_cache = ann_index_cache_info()
-        # live-corpus probes outside the stats lock too: they read the
+        # live-corpus probes outside the stats lock: they read the
         # corpus's atomically-swapped snapshot, no lock ordering to trip
         live_now = {name: fn() for name, fn in list(self._live_fns.items())}
         with self._lock:
@@ -312,14 +363,13 @@ class ServingStats:
                     e2e=LatencySummary.from_samples(ep.e2e),
                     queue_wait_total_s=ep.queue_wait_total_s,
                     execute_total_s=ep.execute_total_s,
+                    phase_total_s=dict(ep.phase_total_s),
                     depth_limit=self._depth_limits.get(name),
                     rejected=ep.overload["rejected"],
                     shed=ep.overload["shed"],
                     backend=self._backends.get(name),
                     corpus_dtype=self._corpus_dtypes.get(name),
                     profile=self._profiles.get(name),
-                    tile_cache=tile_cache,
-                    ann_index_cache=ann_cache,
                     generation=live.get("generation"),
                     segment_rows=live.get("segment_rows"),
                     tombstones=live.get("tombstones"),

@@ -15,6 +15,7 @@ from repro.core.spaces import DenseSpace
 from repro.launch.serve import BatchingServer
 from repro.serving import (QueryCache, RetrievalService, ServiceOverloaded,
                            quantized_key)
+from repro.serving.stats import PHASES
 
 
 @pytest.fixture(scope="module")
@@ -522,6 +523,31 @@ class TestTokensAndStats:
         assert ep.execute_total_s >= 1e-3 * ep.execute.p50_ms  # exact sums
         assert ep.queue_depth == 0
         assert snap.qps > 0
+
+    def test_phase_totals_are_exact(self):
+        """The four timed phases tile a batch's execution: their exact
+        lifetime totals sum to ``execute_total_s`` but for the few
+        bytecodes between spans; the gather and the fan-out are counted
+        beside them, for every batch."""
+        def slow(q, _tokens):
+            time.sleep(0.02)           # inside serve.dispatch
+            return q * 2.0
+
+        svc = RetrievalService(cache_size=0)
+        svc.register_runner("slow", slow, np.zeros(4, np.float32),
+                            batch_size=4, max_wait_s=0.005)
+        with svc:
+            svc.retrieve([np.full(4, i, np.float32) for i in range(10)],
+                         endpoint="slow")
+        ep = svc.snapshot().endpoints["slow"]
+        ph = ep.phase_total_s
+        assert list(ph) == list(PHASES)
+        timed = ph["assemble"] + ph["dispatch"] + ph["sync"] + ph["copy_back"]
+        assert ep.n_batches >= 3
+        assert ph["dispatch"] >= 0.02 * ep.n_batches
+        assert timed <= ep.execute_total_s
+        assert timed == pytest.approx(ep.execute_total_s, rel=1e-2)
+        assert ph["gather"] > 0 and ph["fanout"] > 0
 
     def test_reset_stats_zeroes_but_keeps_endpoints(self, dense_setup):
         """Warm-up isolation: reset zeroes counters, then real load counts

@@ -18,6 +18,7 @@ imports this file.  Keep every compile test in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -50,9 +51,13 @@ def shape(topo):
                                                     sharding=one_chip)
 
 
-def _assert_mosaic(lowered):
+def _assert_mosaic(lowered, name):
+    """Compiled by Mosaic, under the kernel's stable name: the custom
+    call is the instruction a device trace names the kernel by."""
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text, "the kernel was not compiled by Mosaic"
+    named = rf'%{name}(\.\d+)? = .*custom_call_target="tpu_custom_call"'
+    assert re.search(named, text), f"no tpu_custom_call named {name}"
 
 
 @pytest.mark.parametrize("dtype,space,k", [
@@ -69,7 +74,7 @@ def test_mips_topk_compiles(shape, dtype, space, k):
     assert N % tile == 0 and tile % 128 == 0
     _assert_mosaic(ops.mips_topk.lower(
         shape((B, D), jnp.float32), corpus, k, tile_n=tile, space=space,
-        n_valid=N))
+        n_valid=N), "mips_topk")
 
 
 @pytest.mark.parametrize("dtype,k", [
@@ -89,4 +94,5 @@ def test_fused_topk_compiles(shape, dtype, k):
                              shape((N, DOC_NNZ), dtype))
     _assert_mosaic(ops.fused_topk.lower(
         q_sparse, shape((B, D), jnp.float32), c_sparse, shape((N, D), dtype),
-        VOCAB, k, w_dense=0.7, w_sparse=0.3, tile_n=tile, n_valid=N))
+        VOCAB, k, w_dense=0.7, w_sparse=0.3, tile_n=tile, n_valid=N),
+        "fused_topk")
