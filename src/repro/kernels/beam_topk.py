@@ -24,10 +24,10 @@ only O(ef·R) corpus rows:
     (a full copy per step in interpret mode; a VMEM round-trip on TPU);
   * scoring mirrors ``fused_topk.py`` component for component (dense
     ip/l2 einsum groupings, per-nnz-column sparse gather, the one-einsum
-    weighted mix), and the beam merge reuses ``fused_topk``'s running
-    top-k fold (``mips_topk._fold_topk``) so dense, sparse and fused
-    spaces all traverse on-device with the same selection semantics
-    (ties toward the lower concatenation slot, like ``lax.top_k``).
+    weighted mix), and the beam merge is a K-round fold of its own
+    (``_fold_topk``) with the exact kernels' selection semantics, so
+    dense, sparse and fused spaces all traverse on-device alike (ties
+    toward the lower concatenation slot, like ``lax.top_k``).
 
 Candidate semantics (the oracle in ``ref.beam_hop_ref`` re-states these
 independently):
@@ -132,7 +132,8 @@ def unpack_visited(visited: jax.Array, n: int) -> jax.Array:
 
 
 def _fold_topk(scores_row: jax.Array, ids_row: jax.Array, k: int):
-    """``mips_topk._fold_topk`` with ``-inf`` masking instead of ``NEG``.
+    """K rounds of max / argmax / mask over the ``[beam | candidates]``
+    row, masking with ``-inf`` instead of ``NEG``.
 
     The exact kernels never fold past their valid count (the backend
     clamps ``k <= n_valid``), so masking picked slots back to ``NEG``

@@ -8,8 +8,8 @@ Per grid step over corpus tiles:
     score[b, n] = w_dense  * dense_kind(q_dense[b], c_dense[n])     (MXU)
                 + w_sparse * sum_k q[b](c_idx[n, k]) * c_val[n, k]  (VPU+MXU)
     fold the [B, TILE_N] tile scores into the running top-k carried in
-    VMEM scratch (K rounds of max/first-position/mask, from
-    kernels/mips_topk.py)
+    VMEM scratch (kernels/mips_topk.fold_tile: only rows above the
+    running K-th score are inserted, one round each)
 
 so the [B, N] score matrix never exists anywhere — not in HBM (as in
 ``kernels/sparse_dense.py`` + host ``lax.top_k``) and not on the host.
@@ -61,7 +61,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.mips_topk import NEG, _fold_topk, score_tile
+from repro.kernels.mips_topk import NEG, fold_tile, score_tile
 from repro.kernels.platform import pallas_call
 
 
@@ -112,7 +112,7 @@ def sparse_tile(q_ids: jax.Array, q_w: jax.Array, c_idx: jax.Array,
                                preferred_element_type=jnp.float32)
 
 
-def _kernel(*refs, k: int, tile_n: int, n_tiles: int, n_valid: int,
+def _kernel(*refs, tile_n: int, n_tiles: int, n_valid: int,
             w_dense, w_sparse, dense_kind: str, has_dense: bool,
             has_sparse: bool):
     it = iter(refs)
@@ -147,11 +147,7 @@ def _kernel(*refs, k: int, tile_n: int, n_tiles: int, n_valid: int,
     ids = base + jax.lax.broadcasted_iota(jnp.int32, total.shape, 1)
     s = jnp.where(ids < n_valid, total, NEG)
 
-    cat_s = jnp.concatenate([s_scr[...], s], axis=1)
-    cat_i = jnp.concatenate([i_scr[...], ids], axis=1)
-    new_s, new_i = _fold_topk(cat_s, cat_i, k)
-    s_scr[...] = new_s
-    i_scr[...] = new_i
+    fold_tile(s_scr, i_scr, s, base)
 
     @pl.when(t == n_tiles - 1)
     def _emit():
@@ -214,7 +210,7 @@ def fused_topk_pallas(q_ids, q_w, q_dense, c_idx, c_val, c_dense, k: int,
         operands.append(c_dense)                     # dense tiles streamed
 
     kernel = functools.partial(
-        _kernel, k=k, tile_n=tile_n, n_tiles=n_tiles, n_valid=n_valid,
+        _kernel, tile_n=tile_n, n_tiles=n_tiles, n_valid=n_valid,
         w_dense=w_dense if has_dense else None,
         w_sparse=w_sparse if has_sparse else None,
         dense_kind=dense_kind, has_dense=has_dense, has_sparse=has_sparse)

@@ -8,15 +8,22 @@ touches HBM.  Per-device HBM traffic is exactly one read of the corpus
 tile stream plus one [B, K] result write: the kernel is corpus-bandwidth
 bound, which is the roofline for exact k-NN search.
 
-Top-k selection uses K rounds of (max, first position, mask) over the
-concatenated [running-K | tile] score row — branch-free, fully vectorised
-(VPU lane reductions), no data-dependent control flow and no gather.
+Top-k selection is a threshold-gated insertion fold (:func:`fold_tile`):
+only tile rows that beat the running K-th score can enter the list, so a
+tile runs as many insertion rounds as the most such rows any query has
+(at most K).  Each round takes the tile's best remaining candidate per
+query (max, first position: masked VPU lane reductions, no gather) and
+inserts it into the sorted running list with a one-lane shift.  With rows
+in an order unrelated to the query the running K-th score rises fast and
+most tiles need no round at all; a corpus sorted to defeat it costs K
+rounds a tile, as a full K-round fold would.  The kernel also returns the
+number of rounds it ran.
 
 Layout notes (TPU target):
   * TILE_N and D should be multiples of 128 (lane dim / MXU face);
     B is the sublane dim — multiples of 8 for f32.
-  * scratch: scores f32[B, K], ids i32[B, K] in VMEM; outputs are written
-    on the final grid step (pl.when).
+  * scratch: scores f32[B, K], ids i32[B, K] in VMEM and the round count
+    in SMEM; outputs are written on the final grid step (pl.when).
   * scores accumulate in f32 regardless of input dtype (bf16 corpus OK),
     and the MXU contraction runs at ``Precision.HIGHEST``.
 
@@ -41,31 +48,41 @@ from repro.kernels.platform import pallas_call
 
 NEG = float(jnp.finfo(jnp.float32).min)
 _POS_SENTINEL = int(jnp.iinfo(jnp.int32).max)
-_ID_FLOOR = int(jnp.iinfo(jnp.int32).min)
 
 
-def _fold_topk(scores_row: jax.Array, ids_row: jax.Array, k: int):
-    """K rounds of max / first-position / mask over [B, M] ->
-    sorted-descending [B, K].  Branch-free, VPU-only; cost ~K * B * M
-    compares.  Ties go to the lowest position, i.e. the lower corpus row
-    id (the running top-k precedes the tile), like ``lax.top_k``.
+def fold_tile(s_scr, i_scr, s: jax.Array, base) -> jax.Array:
+    """Fold one tile's scores ``s`` f32[B, TILE_N] (row ids ``base`` +
+    lane) into the running top-k ``s_scr``/``i_scr`` [B, K], sorted
+    descending; returns the number of insertion rounds run (i32).
 
-    Every step is a masked lane reduction: Mosaic lowers these, while an
-    ``argmax`` + ``take_along_axis`` id pick lowers to a gather it
-    refuses."""
-    pos = jax.lax.broadcasted_iota(jnp.int32, scores_row.shape, 1)
-    out_s, out_i = [], []
-    cur = scores_row
-    for _ in range(k):
-        mx = jnp.max(cur, axis=1, keepdims=True)
-        first = jnp.min(jnp.where(cur == mx, pos, _POS_SENTINEL), axis=1,
+    A row enters only if it beats the running K-th score: one equal to it
+    loses to the running entry, whose id is lower.  Each round inserts
+    every query's best remaining candidate, at its first position, after
+    the running entries that are not below it, so ties keep the lower id
+    first, as ``lax.top_k`` does.  A query out of candidates picks
+    ``NEG`` and inserts it past the end, which changes nothing.  Every
+    step is a lane reduction, compare or select, or a one-lane
+    ``pltpu.roll``: Mosaic lowers all of them."""
+    k = s_scr.shape[1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, s_scr.shape, 1)
+    above = s > s_scr[:, k - 1:k]
+    rounds = jnp.minimum(jnp.max(jnp.sum(above, axis=1)), k)
+
+    def insert(_, cand):
+        best = jnp.max(cand, axis=1, keepdims=True)
+        first = jnp.min(jnp.where(cand == best, pos, _POS_SENTINEL), axis=1,
                         keepdims=True)
-        hit = pos == first
-        out_s.append(mx)
-        out_i.append(jnp.max(jnp.where(hit, ids_row, _ID_FLOOR), axis=1,
-                             keepdims=True))
-        cur = jnp.where(hit, NEG, cur)
-    return jnp.concatenate(out_s, axis=1), jnp.concatenate(out_i, axis=1)
+        run_s, run_i = s_scr[...], i_scr[...]
+        at = jnp.sum(run_s >= best, axis=1, keepdims=True)
+        s_scr[...] = jnp.where(slot < at, run_s, jnp.where(
+            slot == at, best, pltpu.roll(run_s, 1, 1)))
+        i_scr[...] = jnp.where(slot < at, run_i, jnp.where(
+            slot == at, base + first, pltpu.roll(run_i, 1, 1)))
+        return jnp.where(pos == first, NEG, cand)
+
+    jax.lax.fori_loop(0, rounds, insert, jnp.where(above, s, NEG))
+    return rounds
 
 
 def score_tile(q: jax.Array, c: jax.Array, kind: str) -> jax.Array:
@@ -84,37 +101,35 @@ def score_tile(q: jax.Array, c: jax.Array, kind: str) -> jax.Array:
     return s
 
 
-def _kernel(q_ref, c_ref, out_s_ref, out_i_ref, s_scr, i_scr, *,
-            k: int, tile_n: int, n_tiles: int, n_valid: int, space: str):
+def _kernel(q_ref, c_ref, out_s_ref, out_i_ref, out_r_ref, s_scr, i_scr,
+            r_scr, *, tile_n: int, n_tiles: int, n_valid: int, space: str):
     t = pl.program_id(0)
 
     @pl.when(t == 0)
     def _init():
         s_scr[...] = jnp.full_like(s_scr, NEG)
         i_scr[...] = jnp.zeros_like(i_scr)
+        r_scr[0] = 0
 
     s = score_tile(q_ref[...], c_ref[...], space)          # [B, TILE_N]
     base = t * tile_n
     ids = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(ids < n_valid, s, NEG)
-
-    cat_s = jnp.concatenate([s_scr[...], s], axis=1)     # [B, K+TILE_N]
-    cat_i = jnp.concatenate([i_scr[...], ids], axis=1)
-    new_s, new_i = _fold_topk(cat_s, cat_i, k)
-    s_scr[...] = new_s
-    i_scr[...] = new_i
+    r_scr[0] += fold_tile(s_scr, i_scr, s, base)
 
     @pl.when(t == n_tiles - 1)
     def _emit():
         out_s_ref[...] = s_scr[...]
         out_i_ref[...] = i_scr[...]
+        out_r_ref[0] = r_scr[0]
 
 
 def mips_topk_pallas(queries: jax.Array, corpus: jax.Array, k: int,
                      tile_n: int = 2048, n_valid: int | None = None,
                      space: str = "ip"):
-    """queries [B, D], corpus [N, D] -> (scores [B, K], ids [B, K]),
-    descending.  N must be a multiple of tile_n (pad via
+    """queries [B, D], corpus [N, D] -> (scores [B, K], ids [B, K],
+    rounds i32[1]): the top k descending, and the fold's insertion rounds
+    summed over the tiles.  N must be a multiple of tile_n (pad via
     ``brute_force.pad_corpus``).  ``space``: "ip" | "l2" (negated)."""
     b, d = queries.shape
     n = corpus.shape[0]
@@ -122,9 +137,9 @@ def mips_topk_pallas(queries: jax.Array, corpus: jax.Array, k: int,
     n_tiles = n // tile_n
     n_valid = n if n_valid is None else n_valid
 
-    kernel = functools.partial(_kernel, k=k, tile_n=tile_n, n_tiles=n_tiles,
+    kernel = functools.partial(_kernel, tile_n=tile_n, n_tiles=n_tiles,
                                n_valid=n_valid, space=space)
-    out_s, out_i = pallas_call(
+    return pallas_call(
         kernel,
         name="mips_topk",
         grid=(n_tiles,),
@@ -135,14 +150,16 @@ def mips_topk_pallas(queries: jax.Array, corpus: jax.Array, k: int,
         out_specs=[
             pl.BlockSpec((b, k), lambda t: (0, 0)),
             pl.BlockSpec((b, k), lambda t: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, k), jnp.float32),
             jax.ShapeDtypeStruct((b, k), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((b, k), jnp.float32),
             pltpu.VMEM((b, k), jnp.int32),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )(queries, corpus)
-    return out_s, out_i

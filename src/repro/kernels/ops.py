@@ -36,8 +36,8 @@ def mips_topk(queries: jax.Array, corpus: jax.Array, k: int,
     padded = (n + tile_n - 1) // tile_n * tile_n
     if padded != n:
         corpus = jnp.pad(corpus, ((0, padded - n), (0, 0)))
-    s, i = mips_topk_pallas(queries, corpus, k, tile_n=tile_n,
-                            n_valid=n_valid, space=space)
+    s, i, _ = mips_topk_pallas(queries, corpus, k, tile_n=tile_n,
+                               n_valid=n_valid, space=space)
     return TopK(s, i)
 
 

@@ -82,12 +82,17 @@ def topk_tile_seconds(tile_n: int, *, b: int, k: int, bytes_per_row: float,
     Per tile the kernel streams ``tile_n`` corpus rows from HBM
     (``bytes_per_row`` each), scores them (``flops_per_row`` each — MXU
     matmul and/or sparse gather-FMA), and folds the tile into the running
-    top-k with K rounds of max/argmax/mask over the ``[B, K + tile_n]``
-    concatenation (VPU compares).  The tile time is the max of the
-    compute and HBM-stream terms — the quantity ``tile_n`` auto-tuning
-    (``core.backends.auto_tile_n``) minimises per corpus row: small tiles
-    pay the ``B*K^2`` fold term once per few rows, large tiles stop
-    fitting the VMEM working set."""
+    top-k.  The tile time is the max of the compute and HBM-stream terms
+    — the quantity ``tile_n`` auto-tuning (``core.backends.auto_tile_n``)
+    minimises per corpus row: small tiles pay the ``B*K^2`` fold term
+    once per few rows, large tiles stop fitting the VMEM working set.
+
+    The fold term is stale.  It charges K full rounds over the
+    ``[B, K + tile_n]`` concatenation to the MXU peak, the fold the
+    kernels ran before ``mips_topk.fold_tile``; that fold now inserts
+    only the rows above the running K-th score, a few rounds a tile on
+    rows in an order unrelated to the query.  The model is kept as it
+    is because it picks the tile the served kernels run."""
     peaks = device_peaks()
     compute = (flops_per_row * tile_n + b * k * (k + tile_n)) / peaks.bf16_flops
     memory = (bytes_per_row * tile_n) / peaks.hbm_bytes_per_s

@@ -1,6 +1,8 @@
 """Pallas kernel validation: shape/dtype sweeps vs pure-jnp oracles
 (interpret mode) + hypothesis property tests."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,7 @@ except ImportError:          # bare install: seeded parametrized fallback
 from repro.core.sparse import from_dense, densify
 from repro.core.brute_force import TopK
 from repro.kernels import ops, ref
+from repro.kernels.mips_topk import mips_topk_pallas
 from _precision import assert_topk_close
 
 
@@ -60,6 +63,71 @@ def test_mips_topk_permutation_invariance(seed):
     np.testing.assert_allclose(np.asarray(a.scores), np.asarray(b.scores),
                                rtol=1e-5)
     assert np.array_equal(perm[np.asarray(b.indices)], np.asarray(a.indices))
+
+
+def _fold_case(case: str, b: int):
+    """Integer rows and queries (every score exact in f32, whatever the
+    summation order) -> (queries, corpus, k, tile_n, n_valid, rounds):
+    ``rounds`` is the fold's insertion-round count where the case fixes
+    it, else None."""
+    n, d, tile, k = 512, 8, 128, 10
+    rng = np.random.default_rng(_FOLD_CASES.index(case))
+    q = rng.integers(-3, 4, (b, d))
+    c = rng.integers(-3, 4, (n, d))
+    n_valid, rounds = n, None
+    if case in ("ascending", "descending"):
+        # score of row i is +-(query index + 1) * i
+        c = np.zeros((n, d), np.int64)
+        c[:, 0], c[:, 1] = np.arange(n) >> 8, np.arange(n) & 255
+        sign = 1 if case == "ascending" else -1
+        q = np.zeros((b, d), np.int64)
+        q[:, 0] = sign * 256 * np.arange(1, b + 1)
+        q[:, 1] = sign * np.arange(1, b + 1)
+        # every tile beats the running K-th score / none after the first
+        rounds = k * (n // tile) if case == "ascending" else k
+    elif case == "all_equal":
+        c = np.ones((n, d), np.int64)
+        q = np.ones((b, d), np.int64)
+        rounds = k
+    elif case == "duplicates_across_tiles":
+        q = rng.integers(1, 4, (b, d))
+        c[tile - 6:tile + 6] = 20               # the best rows, tied
+    elif case == "n_valid_below_n":
+        q = rng.integers(1, 4, (b, d))
+        n_valid = 450
+        c[n_valid:] = 20                        # would win if not masked
+    elif case == "k_above_tile":
+        k = 200
+    elif case == "k_equals_tile":
+        k = tile
+    return (jnp.asarray(q, jnp.float32), jnp.asarray(c, jnp.float32), k,
+            tile, n_valid, rounds)
+
+
+_FOLD_CASES = ("ascending", "descending", "all_equal",
+               "duplicates_across_tiles", "n_valid_below_n", "k_above_tile",
+               "k_equals_tile")
+
+
+@pytest.mark.parametrize("b", [1, 16])
+@pytest.mark.parametrize("case", _FOLD_CASES)
+def test_mips_topk_fold_bitwise(case, b):
+    """The threshold-gated fold selects exactly what a stable descending
+    sort does (ties to the lower id), bit for bit, and runs the insertion
+    rounds the case fixes: K on every tile of an ascending corpus, K on
+    the first tile only of a descending or all-equal one."""
+    q, c, k, tile, n_valid, rounds = _fold_case(case, b)
+    s, i, r = jax.jit(functools.partial(mips_topk_pallas, k=k, tile_n=tile,
+                                        n_valid=n_valid))(q, c)
+    scores = (np.asarray(q, np.float64) @ np.asarray(c, np.float64).T
+              )[:, :n_valid].astype(np.float32)
+    want_i = np.argsort(-scores, axis=1, kind="stable")[:, :k]
+    want_s = np.take_along_axis(scores, want_i, axis=1)
+    assert np.array_equal(np.asarray(i), want_i)
+    assert np.asarray(s).tobytes() == want_s.tobytes()
+    assert 0 < int(r[0]) <= k * (c.shape[0] // tile)
+    if rounds is not None:
+        assert int(r[0]) == rounds
 
 
 @pytest.mark.parametrize("b,n,v,nnz,dd,tile", [
