@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
-from perfbench import work
+from perfbench import traffic, work
 from perfbench.references import dense_ip
 from repro.core.backends import resolve_backend
 from repro.core.pipeline import BruteForceGenerator, RetrievalPipeline
@@ -102,6 +102,9 @@ class Deployment:
             pipe.close()
         self._owned = []
 
+    def make_queries(self, count: int, rng: np.random.Generator):
+        return traffic.make_queries(count, self.dim, rng)
+
     # -- what the reference reads ------------------------------------------
     def reference(self, queries: np.ndarray, m: int,
                   precision: str = "highest"):
@@ -120,6 +123,14 @@ class Deployment:
         order = np.argsort(-scores, axis=1, kind="stable")[:, :m]
         return (np.take_along_axis(scores, order, 1),
                 np.take_along_axis(ids, order, 1))
+
+    def control(self, queries: np.ndarray, m: int, precision: str,
+                emulate: bool):
+        """The reference scan at ``precision``; where ``emulate``, over
+        the queries with their last 8 mantissa bits dropped (what a
+        three-pass product reads of them)."""
+        scan_q = dense_ip.query_bits_16(queries) if emulate else queries
+        return self.reference(scan_q, m, precision=precision)
 
     def rows(self, ids: np.ndarray) -> np.ndarray:
         """[S, R] global ids (each in range) -> [S, R, D] rows as f32."""

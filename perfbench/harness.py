@@ -7,10 +7,34 @@ Everything a cell is made of is found by name:
   configuration, traffic mix and metrics;
 * the configuration is the JSON file its entry names; its ``builder``
   is a module under ``builders/``, which holds the data, registers the
-  endpoint and answers through its plain reference (``references/``);
+  endpoint, draws the queries and answers through its plain reference
+  (``references/``);
 * a traffic mix is ``traffic/<name>.json``, read by ``traffic.py``;
 * a per-layer metric is ``metrics/<name>.py``, whose ``read(layers)``
   returns the number or None when there is nothing to read.
+
+A builder module has ``build(cfg, seed, devices)``, which makes the
+deployment's data from the seed on ``devices`` and returns an object
+with:
+
+* ``register(svc, name)``: serve the deployment as endpoint ``name`` of
+  the ``RetrievalService`` ``svc``, as a user of the program would;
+* ``make_queries(count, rng)``: a pool of ``count`` queries drawn from
+  ``rng``, in the form the endpoint takes, as any pytree whose every
+  leaf leads with ``count`` (one f32 array for a dense space);
+  ``traffic.take`` is the only way the harness indexes it;
+* ``reference(queries, m)``: the ``m`` best (scores, global ids) per
+  query of a pool, by the plain reference, which imports nothing of the
+  program;
+* ``exact(queries, ids)``: the exact (f64) score of each ``[S, R]`` id;
+* ``control(queries, m, precision, emulate)``: (scores, ids) as the
+  reference computes them in a precision below the configuration's, or
+  with the queries' own precision lowered where ``emulate``: what
+  ``tools/calibrate.py`` holds the limits against;
+* ``scan_work(batch)``: the bytes and operations of one served scan on
+  one chip (``work.scan_work``);
+* ``close_program()``: stop what ``register`` started;
+* ``delete()``: free the data on the devices.
 """
 
 from __future__ import annotations
@@ -189,7 +213,7 @@ class Window:
     endpoint's stats at its close."""
 
     sched: traffic.Schedule
-    queries: np.ndarray
+    queries: object               # the pool, as make_queries drew it
     client: traffic.OpenLoopClient
     stats: object
     window_s: float
@@ -230,10 +254,11 @@ class Cell:
         """Every shape the window uses: full batches and a part batch,
         of queries the window never sends."""
         n = 2 * self.batch
-        qs = traffic.make_queries(n + 1, self.cfg["dim"], rng)
-        for f in [self.svc.submit(q, endpoint=self.name) for q in qs[:n]]:
+        qs = self.dep.make_queries(n + 1, rng)
+        for f in [self.svc.submit(traffic.take(qs, i), endpoint=self.name)
+                  for i in range(n)]:
             f.result()
-        self.svc.submit(qs[n], endpoint=self.name).result()
+        self.svc.submit(traffic.take(qs, n), endpoint=self.name).result()
         self.svc.reset_stats()
 
     def drive(self, rate: float, seconds: float, rng: np.random.Generator,
@@ -243,8 +268,8 @@ class Cell:
         import jax
 
         sched = traffic.schedule(self.mix, rate, seconds, rng)
-        queries = traffic.make_queries(traffic.pool_size(sched),
-                                       self.cfg["dim"], rng)
+        queries = self.dep.make_queries(traffic.pool_size(sched), rng)
+        requests = [traffic.take(queries, qi) for qi in sched.query]
         self.svc.reset_stats()
         compiles0, events0 = COMPILES.count, dict(COMPILES.by_name)
         if trace_dir is not None:
@@ -252,7 +277,7 @@ class Cell:
             jax.profiler.start_trace(
                 str(trace_dir), profiler_options=tracing.profile_options())
         client = traffic.OpenLoopClient(
-            lambda q: self.svc.submit(q, endpoint=self.name), queries, sched)
+            lambda q: self.svc.submit(q, endpoint=self.name), requests, sched)
         pauses = _Pauses()
         gc.callbacks.append(pauses)
         try:
@@ -289,7 +314,7 @@ class Cell:
         k = cfg["final_qty"]
         served_s, served_i = _stack(
             [win.client.futures[i].result() for i in sample], k)
-        qs = win.queries[win.sched.query[sample]]
+        qs = traffic.take(win.queries, win.sched.query[sample])
         _, cand = dep.reference(qs, cfg["ref_candidates"])
         cand_exact = dep.exact(qs, cand)
         exact_top = -np.sort(-cand_exact, axis=1)[:, :k]

@@ -6,13 +6,14 @@ program and for the control, on many seeds in one process.
 
 For each seed the cell runs as the benchmark runs it (data made anew
 from the seed, a short window at the cell's rate) and its answers are
-compared with the reference.  Then the controls take the program's place
-on the same sampled queries: the reference scan computed in a lower
-precision than the configuration states (``Precision.HIGH``, three bf16
-passes; its portable equivalent, the queries' last 8 mantissa bits
-dropped; and ``Precision.DEFAULT``, one bf16 pass).  One JSON line per
-seed; the limits are set between the largest program reading and the
-smallest control reading (``PERF.md``).
+compared with the reference.  Then the deployment's controls take the
+program's place on the same sampled queries (its builder's ``control``):
+for a dense scan, the reference computed in a lower precision than the
+configuration states (``Precision.HIGH``, three bf16 passes; its
+portable equivalent, the queries' last 8 mantissa bits dropped; and
+``Precision.DEFAULT``, one bf16 pass).  One JSON line per seed; the
+limits are set between the largest program reading and the smallest
+control reading (``PERF.md``).
 """
 
 import argparse
@@ -25,13 +26,13 @@ ROOT = Path(__file__).resolve().parents[2]
 
 
 def control_numbers(cell, checked, precision: str, emulate: bool):
+    """The numbers ``correct`` compares, read off the deployment's
+    control put in the program's place on the checked queries."""
     from perfbench import checks
-    from perfbench.references import dense_ip
 
     qs = checked["queries"]
     k = cell.cfg["final_qty"]
-    scan_q = dense_ip.query_bits_16(qs) if emulate else qs
-    scores, ids = cell.dep.reference(scan_q, k, precision=precision)
+    scores, ids = cell.dep.control(qs, k, precision, emulate)
     exact_served = cell.dep.exact(qs, ids)
     return checks.compare(scores, ids, checked["exact_top"], exact_served,
                           cell.cfg["rows"])

@@ -21,6 +21,10 @@ Every seed gets the same work: the same number of requests, the same
 multiset of gaps (the exponential's quantiles) and of query popularity
 ranks, in an order and with query vectors drawn from the seed.  So two
 seeds differ in what they send and in what order, not in how much.
+
+The queries themselves are the deployment's: its builder's
+``make_queries`` draws a pool (any pytree whose leaves lead with the
+pool's axis), and ``take`` indexes it.
 """
 
 from __future__ import annotations
@@ -28,8 +32,9 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from typing import Callable, List
+from typing import Callable, List, Sequence
 
+import jax
 import numpy as np
 
 
@@ -89,20 +94,30 @@ def pool_size(sched: Schedule) -> int:
 
 
 def make_queries(count: int, dim: int, rng: np.random.Generator) -> np.ndarray:
-    """``count`` query vectors, f32 on the host as a client sends them:
-    entries N(0, 1/dim), so a query has about unit norm."""
+    """``count`` dense query vectors, f32 on the host as a client sends
+    them: entries N(0, 1/dim), so a query has about unit norm."""
     return (rng.standard_normal((count, dim), dtype=np.float32)
             / np.float32(np.sqrt(dim)))
+
+
+def take(pool, idx):
+    """``x[idx]`` of every leaf of a query pool: one query for an integer
+    ``idx``, a smaller pool for an index array."""
+    return jax.tree.map(lambda x: x[idx], pool)
 
 
 class OpenLoopClient:
     """One thread that submits each request at its due time, whether or
     not earlier ones have come back, and records when each future
-    resolves (the callback runs where the result is set)."""
+    resolves (the callback runs where the result is set).
 
-    def __init__(self, submit: Callable, queries: np.ndarray,
+    ``requests`` holds each scheduled request's query, split from the
+    pool before the window opens, so that sending one costs no more than
+    a list's lookup whatever the pool's structure."""
+
+    def __init__(self, submit: Callable, requests: Sequence,
                  sched: Schedule):
-        self.submit, self.queries, self.sched = submit, queries, sched
+        self.submit, self.requests, self.sched = submit, requests, sched
         m = sched.due_s.size
         self.sent = np.full(m, np.nan)
         self.done = np.full(m, np.nan)
@@ -127,13 +142,13 @@ class OpenLoopClient:
     def run(self, window_s: float) -> float:
         """Send the whole schedule; returns when the window has closed."""
         self.t0 = t0 = time.perf_counter()
-        for i, (due, qi) in enumerate(zip(self.sched.due_s,
-                                          self.sched.query)):
+        for i, (due, query) in enumerate(zip(self.sched.due_s,
+                                             self.requests)):
             wait = t0 + due - time.perf_counter()
             if wait > 0:
                 time.sleep(wait)
             self.sent[i] = time.perf_counter() - t0
-            fut = self.submit(self.queries[qi])
+            fut = self.submit(query)
             self.futures[i] = fut
             fut.add_done_callback(lambda f, i=i: self._resolved(i, f))
         rest = t0 + window_s - time.perf_counter()
