@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.sparse import SparseVectors
+from repro.core.spaces import FusedVectors
 from repro.serving.stats import ServingStats, span
 
 __all__ = ["Request", "ContinuousBatcher", "ServiceOverloaded",
@@ -75,6 +77,17 @@ class Request:
     # a newer snapshot, the service re-keys the stored result to the
     # generation that actually produced it
     generation: Optional[int] = None
+    # live sparse query terms (ids in [0, vocab)), counted at admission
+    # on endpoints whose queries have a sparse part
+    query_terms: int = 0
+
+
+def _sparse_part(query_repr) -> Optional[SparseVectors]:
+    if isinstance(query_repr, FusedVectors):
+        return query_repr.sparse
+    if isinstance(query_repr, SparseVectors):
+        return query_repr
+    return None
 
 
 class _AdmissionQueue:
@@ -167,6 +180,7 @@ class ContinuousBatcher:
         backend: Optional[str] = None,
         corpus_dtype: Optional[str] = None,
         profile: Optional[str] = None,
+        query_vocab: Optional[int] = None,
         stats: Optional[ServingStats] = None,
         on_result: Optional[Callable[[Request, Any], None]] = None,
         time_fn: Callable[[], float] = time.monotonic,
@@ -191,6 +205,15 @@ class ContinuousBatcher:
         # tuned-profile tag (TunedProfile.tag) when this endpoint's knobs
         # came from an autotuned profile: provenance in snapshots + keys
         self.profile = profile
+        # sparse query terms: with the vocabulary of the endpoint's
+        # space, the slots of the pad query's sparse part are the slots
+        # the scan matches per row of a batch; 0 turns the term counters
+        # off (dense endpoints walk nothing per request)
+        pad_sparse = _sparse_part(pad_query_repr)
+        self._query_vocab = query_vocab
+        self._term_slots = (int(np.shape(pad_sparse.indices)[-1])
+                            if query_vocab is not None
+                            and pad_sparse is not None else 0)
         self.stats = stats if stats is not None else ServingStats()
         self.on_result = on_result
         self._time_fn = time_fn
@@ -211,6 +234,10 @@ class ContinuousBatcher:
                 f"endpoint {self.name!r} was registered without "
                 "pad_q_tokens, so per-request q_tokens would be silently "
                 "dropped; register the endpoint with a pad_q_tokens value")
+        if self._term_slots:
+            ids = np.asarray(_sparse_part(request.query_repr).indices)
+            request.query_terms = int(np.count_nonzero(
+                (ids >= 0) & (ids < self._query_vocab)))
         try:
             shed = self._queue.put(request)
         except ServiceOverloaded:
@@ -325,7 +352,9 @@ class ContinuousBatcher:
                 self.name, served=len(batch), capacity=self.batch_size,
                 closed_by=closed_by,
                 queue_waits_s=[t0 - r.t_admit for r in batch],
-                exec_s=t1 - t0, phases=phases)
+                exec_s=t1 - t0, phases=phases,
+                query_terms=sum(r.query_terms for r in batch),
+                term_slots=self.batch_size * self._term_slots)
             fanout: dict = {}
             with span("serve.fanout", fanout):
                 for i, r in enumerate(batch):

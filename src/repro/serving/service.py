@@ -122,6 +122,23 @@ def _pipeline_corpus_dtype(pipeline) -> Optional[str]:
     return None
 
 
+def _query_vocab(pipeline) -> Optional[int]:
+    """The vocabulary of the space behind a pipeline's candidate stage
+    (None for a space without a sparse part, or no space to be found):
+    what the endpoint's sparse query term counters count against."""
+    node = pipeline
+    for _ in range(4):      # funnel -> sharded -> generator -> live corpus
+        if node is None:
+            return None
+        space = getattr(node, "space", None)
+        if space is not None:
+            return getattr(space, "vocab_size", None)
+        gens = getattr(node, "generators", None)        # ShardedPipeline
+        node = (gens[0] if gens else getattr(node, "generator", None)
+                or getattr(node, "live", None))         # LiveGenerator
+    return None
+
+
 class RetrievalService:
     """Multi-endpoint async retrieval with continuous batching + caching.
 
@@ -155,7 +172,7 @@ class RetrievalService:
         batch_size: int = 16, max_wait_s: float = 0.01, jit: bool = False,
         max_queue: Optional[int] = None, overload: str = "block",
         backend: Optional[Any] = None, corpus_dtype: Optional[str] = None,
-        profile: Optional[Any] = None,
+        profile: Optional[Any] = None, query_vocab: Optional[int] = None,
     ) -> "RetrievalService":
         """``spec`` (an :class:`~repro.serving.spec.EndpointSpec`)
         carries every registration knob as one validated value — the
@@ -178,7 +195,12 @@ class RetrievalService:
         The profile's ``tag`` is surfaced in snapshots and folded into
         this endpoint's cache keys (provenance).  Note
         ``profile.config.cache_size`` is a *service*-level knob — pass
-        it to the :class:`RetrievalService` constructor."""
+        it to the :class:`RetrievalService` constructor.
+
+        ``query_vocab`` (the vocabulary of a sparse or fused space; set by
+        :meth:`register_pipeline` from the pipeline's space) turns on the
+        endpoint's sparse query term counters, ``query_terms_total`` and
+        ``query_term_slots_total``, when the pad query has a sparse part."""
         if spec is not None:
             _no_kwargs_alongside_spec(
                 batch_size=batch_size, max_wait_s=max_wait_s, jit=jit,
@@ -212,8 +234,8 @@ class RetrievalService:
             backend=backend_identity(spec.backend),
             corpus_dtype=spec.corpus_dtype,
             profile=None if spec.profile is None else spec.profile.tag,
-            stats=self.stats, on_result=self._on_result,
-            time_fn=self._time_fn)
+            query_vocab=query_vocab, stats=self.stats,
+            on_result=self._on_result, time_fn=self._time_fn)
         self.router.register(batcher)
         return self
 
@@ -322,7 +344,8 @@ class RetrievalService:
                 spec=dataclasses.replace(
                     spec, live=None,
                     backend=backend_identity(live.main_backend),
-                    corpus_dtype=live.corpus_dtype))
+                    corpus_dtype=live.corpus_dtype),
+                query_vocab=_query_vocab(pipeline))
             self.stats.register_endpoint(name, live_fn=live.live_stats)
             self._live_endpoints[name] = (
                 live, lambda: generator.last_served_generation)
@@ -375,7 +398,8 @@ class RetrievalService:
         return self.register_runner(
             name, run_fn, pad_query_repr, pad_q_tokens,
             spec=dataclasses.replace(spec, backend=label,
-                                     corpus_dtype=dtype_label))
+                                     corpus_dtype=dtype_label),
+            query_vocab=_query_vocab(pipeline))
 
     @staticmethod
     def _bind_funnel_knobs(pipeline, spec: EndpointSpec):

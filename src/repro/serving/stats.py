@@ -128,6 +128,12 @@ class EndpointSnapshot:
     # ``execute_total_s``), then the fan-out to the requests' futures
     phase_total_s: Dict[str, float] = dataclasses.field(
         default_factory=lambda: dict.fromkeys(PHASES, 0.0))
+    # sparse query terms (exact lifetime counters; 0 on endpoints whose
+    # queries have no sparse part): live term slots of the requests
+    # served, and the slots the scan matched, batch size times slots
+    # per executed batch, pad rows included
+    query_terms_total: int = 0
+    query_term_slots_total: int = 0
     # admission control (exact lifetime counters)
     depth_limit: Optional[int] = None   # None = unbounded queue
     rejected: int = 0               # submits refused under policy "reject"
@@ -201,6 +207,8 @@ class _EndpointStats:
         self.queue_wait_total_s = 0.0
         self.execute_total_s = 0.0
         self.phase_total_s = dict.fromkeys(PHASES, 0.0)
+        self.query_terms_total = 0
+        self.query_term_slots_total = 0
         self.overload = collections.Counter()   # "rejected" / "shed"
         # staged-funnel recorders, keyed by stage name ("candgen" /
         # "fusion" / "rerank"): latency reservoirs, exact execution /
@@ -280,9 +288,12 @@ class ServingStats:
 
     def record_batch(self, endpoint: str, *, served: int, capacity: int,
                      closed_by: str, queue_waits_s, exec_s: float,
-                     phases: Optional[Dict[str, float]] = None):
+                     phases: Optional[Dict[str, float]] = None,
+                     query_terms: int = 0, term_slots: int = 0):
         """One executed batch; ``phases`` holds the seconds of the phases
-        it has passed so far (``span``'s per-batch dict)."""
+        it has passed so far (``span``'s per-batch dict); ``query_terms``
+        the live sparse query terms of its requests and ``term_slots``
+        the term slots its scan matched."""
         with self._lock:
             ep = self._ep(endpoint)
             ep.n_batches += 1
@@ -294,6 +305,8 @@ class ServingStats:
             ep.execute_total_s += exec_s
             for phase, seconds in (phases or {}).items():
                 ep.phase_total_s[phase] += seconds
+            ep.query_terms_total += query_terms
+            ep.query_term_slots_total += term_slots
 
     def record_fanout(self, endpoint: str, seconds: float):
         """The fan-out of a batch already recorded: it ends when the last
@@ -364,6 +377,8 @@ class ServingStats:
                     queue_wait_total_s=ep.queue_wait_total_s,
                     execute_total_s=ep.execute_total_s,
                     phase_total_s=dict(ep.phase_total_s),
+                    query_terms_total=ep.query_terms_total,
+                    query_term_slots_total=ep.query_term_slots_total,
                     depth_limit=self._depth_limits.get(name),
                     rejected=ep.overload["rejected"],
                     shed=ep.overload["shed"],

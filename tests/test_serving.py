@@ -11,7 +11,9 @@ import numpy as np
 import pytest
 
 from repro.core.pipeline import BruteForceGenerator, RetrievalPipeline
-from repro.core.spaces import DenseSpace
+from repro.core.sparse import SparseVectors
+from repro.core.spaces import (DenseSpace, FusedSpace, FusedVectors,
+                               SparseSpace)
 from repro.launch.serve import BatchingServer
 from repro.serving import (QueryCache, RetrievalService, ServiceOverloaded,
                            quantized_key)
@@ -548,6 +550,58 @@ class TestTokensAndStats:
         assert timed <= ep.execute_total_s
         assert timed == pytest.approx(ep.execute_total_s, rel=1e-2)
         assert ph["gather"] > 0 and ph["fanout"] > 0
+
+    @pytest.mark.parametrize("kind", ["fused", "sparse"])
+    def test_query_term_counters_count_live_slots(self, kind):
+        """The sparse query term counters: every live id in [0, vocab) of
+        the served requests, against batch size x slots per executed
+        batch, pad rows included.  Padding ids (vocab) and ids out of
+        range count in the slots and not among the live terms."""
+        vocab, slots, nnz = 64, 5, 4
+        rng = np.random.default_rng(3)
+        c_sparse = SparseVectors(
+            np.argsort(rng.random((256, vocab)), 1)[:, :nnz].astype(np.int32),
+            rng.random((256, nnz)).astype(np.float32))
+        c_dense = rng.standard_normal((256, 16)).astype(np.float32)
+        q_ids = rng.integers(0, vocab, (20, slots)).astype(np.int32)
+        live = rng.integers(0, slots + 1, 20)
+        q_ids[np.arange(slots) >= live[:, None]] = vocab
+        q_ids[0, -1] = -1                 # out of range: matches nothing
+        q_ids[1, -1] = vocab + 7
+        q_vals = rng.random((20, slots)).astype(np.float32)
+        if kind == "fused":
+            space = FusedSpace(vocab, w_dense=0.7, w_sparse=0.3)
+            corpus = FusedVectors(c_dense, c_sparse)
+            queries = [FusedVectors(rng.standard_normal(16).astype(
+                np.float32), SparseVectors(q_ids[i], q_vals[i]))
+                for i in range(20)]
+            pad = FusedVectors(np.zeros(16, np.float32), SparseVectors(
+                np.full(slots, vocab, np.int32), np.zeros(slots, np.float32)))
+        else:
+            space, corpus = SparseSpace(vocab), c_sparse
+            queries = [SparseVectors(q_ids[i], q_vals[i]) for i in range(20)]
+            pad = SparseVectors(np.full(slots, vocab, np.int32),
+                                np.zeros(slots, np.float32))
+        pipe = RetrievalPipeline(BruteForceGenerator(space, corpus),
+                                 cand_qty=10, final_qty=5)
+        svc = RetrievalService(cache_size=0)
+        svc.register_pipeline("terms", pipe, pad, batch_size=16,
+                              max_wait_s=0.005)
+        with svc:
+            svc.retrieve(queries, endpoint="terms")
+            ep = svc.snapshot().endpoints["terms"]
+        assert ep.query_terms_total == int(
+            ((q_ids >= 0) & (q_ids < vocab)).sum())
+        assert ep.query_term_slots_total == ep.n_batches * 16 * slots
+        assert 0 < ep.query_terms_total < ep.query_term_slots_total
+
+    def test_query_term_counters_zero_on_a_dense_endpoint(self, dense_setup):
+        pipe, q = dense_setup
+        with _service(pipe, q, cache_size=0, max_wait_s=0.005) as svc:
+            svc.retrieve([q[i] for i in range(5)], endpoint="dense")
+            ep = svc.snapshot().endpoints["dense"]
+        assert ep.n_batches >= 1
+        assert ep.query_terms_total == 0 and ep.query_term_slots_total == 0
 
     def test_reset_stats_zeroes_but_keeps_endpoints(self, dense_setup):
         """Warm-up isolation: reset zeroes counters, then real load counts

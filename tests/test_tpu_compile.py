@@ -77,22 +77,25 @@ def test_mips_topk_compiles(shape, dtype, space, k):
         n_valid=N), "mips_topk")
 
 
-@pytest.mark.parametrize("dtype,k", [
-    (jnp.float32, 10),
-    (jnp.bfloat16, 10),
-    (jnp.float32, 100),
+@pytest.mark.parametrize("dtype,k,n", [
+    pytest.param(jnp.float32, 10, N, id="float32-10"),
+    pytest.param(jnp.bfloat16, 10, N, id="bfloat16-10"),
+    pytest.param(jnp.float32, 100, N, id="float32-100"),
+    # the benchmark's fused cell: one chip's 2^22 rows, bf16 dense parts
+    # and term values, the candidate depth
+    pytest.param(jnp.bfloat16, 100, 1 << 22, id="bfloat16-100-4194304"),
 ])
-def test_fused_topk_compiles(shape, dtype, k):
+def test_fused_topk_compiles(shape, dtype, k, n):
     itemsize = jnp.dtype(dtype).itemsize
-    tile = PallasBackend()._fused_tile(N, B, k, QUERY_NNZ, DOC_NNZ, D,
+    tile = PallasBackend()._fused_tile(n, B, k, QUERY_NNZ, DOC_NNZ, D,
                                        val_itemsize=itemsize,
                                        dense_itemsize=itemsize)
-    assert N % tile == 0 and tile % 128 == 0
+    assert n % tile == 0 and tile % 128 == 0
     q_sparse = SparseVectors(shape((B, QUERY_NNZ), jnp.int32),
                              shape((B, QUERY_NNZ), jnp.float32))
-    c_sparse = SparseVectors(shape((N, DOC_NNZ), jnp.int32),
-                             shape((N, DOC_NNZ), dtype))
+    c_sparse = SparseVectors(shape((n, DOC_NNZ), jnp.int32),
+                             shape((n, DOC_NNZ), dtype))
     _assert_mosaic(ops.fused_topk.lower(
-        q_sparse, shape((B, D), jnp.float32), c_sparse, shape((N, D), dtype),
-        VOCAB, k, w_dense=0.7, w_sparse=0.3, tile_n=tile, n_valid=N),
+        q_sparse, shape((B, D), jnp.float32), c_sparse, shape((n, D), dtype),
+        VOCAB, k, w_dense=0.7, w_sparse=0.3, tile_n=tile, n_valid=n),
         "fused_topk")
