@@ -11,7 +11,10 @@ worker thread.  The worker closes a batch on whichever knob trips first:
 Partial batches are padded to the fixed ``batch_size`` with the
 endpoint's pad query (jit shape stability — the padded rows are scored
 and discarded), run through the endpoint's batched runner, and the rows
-fan back out to per-request futures.  A runner failure fails every
+fan back out to per-request futures.  Each leaf of the batch's query
+pytree that every query holds on the host is stacked there and moved to
+the device in one transfer; a leaf any query holds on a device is
+stacked on the device (``jnp.stack``).  A runner failure fails every
 future in the batch; the worker survives and keeps serving.
 
 Admission control: ``max_queue`` bounds the per-endpoint queue depth.
@@ -80,6 +83,34 @@ class Request:
     # live sparse query terms (ids in [0, vocab)), counted at admission
     # on endpoints whose queries have a sparse part
     query_terms: int = 0
+
+
+def _on_host(x) -> bool:
+    return isinstance(x, (np.ndarray, np.generic))
+
+
+def _stack(trees: list, where: set):
+    """Stack ``trees`` leaf by leaf, as ``jnp.stack`` would, dtypes
+    included.  A leaf that every tree holds on the host is stacked there
+    and moved in one transfer; a leaf any tree holds on a device keeps
+    ``jnp.stack``, which pulls nothing back to the host.  Adds
+    ``"host"`` or ``"device"`` to ``where`` for each leaf."""
+    def leaf(*xs):
+        if all(map(_on_host, xs)):
+            where.add("host")
+            # one array of each dtype: the promotion of all of them
+            dtype = jnp.result_type(*{x.dtype: x for x in xs}.values())
+            return jax.device_put(np.stack(xs, dtype=dtype))
+        where.add("device")
+        return jnp.stack(xs)
+
+    return jax.tree.map(leaf, *trees)
+
+
+def _host_copy(tree):
+    """``tree`` with its host leaves as NumPy arrays (NumPy scalars
+    become 0-d arrays) and its device leaves as they are."""
+    return jax.tree.map(lambda x: np.asarray(x) if _on_host(x) else x, tree)
 
 
 def _sparse_part(query_repr) -> Optional[SparseVectors]:
@@ -189,8 +220,8 @@ class ContinuousBatcher:
             raise ValueError("batch_size must be >= 1")
         self.name = name
         self.run_fn = run_fn
-        self.pad_query_repr = pad_query_repr
-        self.pad_q_tokens = pad_q_tokens
+        self.pad_query_repr = _host_copy(pad_query_repr)
+        self.pad_q_tokens = _host_copy(pad_q_tokens)
         self.batch_size = batch_size
         self.max_wait_s = max_wait_s
         self.max_queue = max_queue
@@ -217,6 +248,10 @@ class ContinuousBatcher:
         self.stats = stats if stats is not None else ServingStats()
         self.on_result = on_result
         self._time_fn = time_fn
+        # where the last batch's leaves were stacked ("host", "device" or
+        # "mixed"): set by ``_assemble``, read by ``_execute``, both on
+        # the worker thread
+        self._stacked_on = "host"
         self._queue = _AdmissionQueue(name, max_queue, overload)
         self._stop = threading.Event()
         self._thread = threading.Thread(
@@ -308,12 +343,15 @@ class ContinuousBatcher:
 
     def _assemble(self, batch: List[Request]):
         n_pad = self.batch_size - len(batch)
+        where: set = set()
         reprs = [r.query_repr for r in batch] + [self.pad_query_repr] * n_pad
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *reprs)
-        if self.pad_q_tokens is None:
-            return stacked, None
-        toks = [r.q_tokens for r in batch] + [self.pad_q_tokens] * n_pad
-        return stacked, jax.tree.map(lambda *xs: jnp.stack(xs), *toks)
+        stacked = _stack(reprs, where)
+        tokens = None
+        if self.pad_q_tokens is not None:
+            toks = [r.q_tokens for r in batch] + [self.pad_q_tokens] * n_pad
+            tokens = _stack(toks, where)
+        self._stacked_on = where.pop() if len(where) == 1 else "mixed"
+        return stacked, tokens
 
     def _execute(self, batch: List[Request], closed_by: str, seq: int,
                  phases: dict):
@@ -324,8 +362,9 @@ class ContinuousBatcher:
                   served=len(batch)):
             t0 = self._time_fn()
             try:
-                with span("serve.assemble", phases):
+                with span("serve.assemble", phases) as sp:
                     stacked, tokens = self._assemble(batch)
+                    sp.set(stack=self._stacked_on)
                 with span("serve.dispatch", phases):
                     if getattr(self.run_fn, "budget_aware", False):
                         # budget-aware runners (the served funnel) get the
@@ -354,7 +393,8 @@ class ContinuousBatcher:
                 queue_waits_s=[t0 - r.t_admit for r in batch],
                 exec_s=t1 - t0, phases=phases,
                 query_terms=sum(r.query_terms for r in batch),
-                term_slots=self.batch_size * self._term_slots)
+                term_slots=self.batch_size * self._term_slots,
+                host_assembled=self._stacked_on == "host")
             fanout: dict = {}
             with span("serve.fanout", fanout):
                 for i, r in enumerate(batch):

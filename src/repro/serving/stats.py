@@ -134,6 +134,10 @@ class EndpointSnapshot:
     # per executed batch, pad rows included
     query_terms_total: int = 0
     query_term_slots_total: int = 0
+    # batches whose every query leaf was stacked on the host and moved to
+    # the device in one transfer (exact lifetime counter; the others
+    # stacked at least one leaf on the device, where a query held it)
+    host_assembled_total: int = 0
     # admission control (exact lifetime counters)
     depth_limit: Optional[int] = None   # None = unbounded queue
     rejected: int = 0               # submits refused under policy "reject"
@@ -209,6 +213,7 @@ class _EndpointStats:
         self.phase_total_s = dict.fromkeys(PHASES, 0.0)
         self.query_terms_total = 0
         self.query_term_slots_total = 0
+        self.host_assembled_total = 0
         self.overload = collections.Counter()   # "rejected" / "shed"
         # staged-funnel recorders, keyed by stage name ("candgen" /
         # "fusion" / "rerank"): latency reservoirs, exact execution /
@@ -289,11 +294,13 @@ class ServingStats:
     def record_batch(self, endpoint: str, *, served: int, capacity: int,
                      closed_by: str, queue_waits_s, exec_s: float,
                      phases: Optional[Dict[str, float]] = None,
-                     query_terms: int = 0, term_slots: int = 0):
+                     query_terms: int = 0, term_slots: int = 0,
+                     host_assembled: bool = False):
         """One executed batch; ``phases`` holds the seconds of the phases
         it has passed so far (``span``'s per-batch dict); ``query_terms``
         the live sparse query terms of its requests and ``term_slots``
-        the term slots its scan matched."""
+        the term slots its scan matched; ``host_assembled`` whether every
+        leaf of it was stacked on the host."""
         with self._lock:
             ep = self._ep(endpoint)
             ep.n_batches += 1
@@ -307,6 +314,7 @@ class ServingStats:
                 ep.phase_total_s[phase] += seconds
             ep.query_terms_total += query_terms
             ep.query_term_slots_total += term_slots
+            ep.host_assembled_total += host_assembled
 
     def record_fanout(self, endpoint: str, seconds: float):
         """The fan-out of a batch already recorded: it ends when the last
@@ -379,6 +387,7 @@ class ServingStats:
                     phase_total_s=dict(ep.phase_total_s),
                     query_terms_total=ep.query_terms_total,
                     query_term_slots_total=ep.query_term_slots_total,
+                    host_assembled_total=ep.host_assembled_total,
                     depth_limit=self._depth_limits.get(name),
                     rejected=ep.overload["rejected"],
                     shed=ep.overload["shed"],

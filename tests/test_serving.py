@@ -4,6 +4,7 @@ pipeline (the sharded-endpoint contract lives in test_sharded.py)."""
 
 import threading
 import time
+from concurrent.futures import Future
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,7 @@ from repro.core.spaces import (DenseSpace, FusedSpace, FusedVectors,
 from repro.launch.serve import BatchingServer
 from repro.serving import (QueryCache, RetrievalService, ServiceOverloaded,
                            quantized_key)
+from repro.serving.batcher import ContinuousBatcher, Request
 from repro.serving.stats import PHASES
 
 
@@ -618,3 +620,145 @@ class TestTokensAndStats:
             snap1 = svc.snapshot()
         assert snap1.n_requests == 1
         assert snap1.endpoints["dense"].n_batches == 1
+
+
+def _fused_query(rng, ids_dtype=np.int32, vals_dtype=np.float32):
+    return FusedVectors(rng.standard_normal(8).astype(np.float32),
+                        SparseVectors(rng.integers(0, 50, 4).astype(ids_dtype),
+                                      rng.random(4).astype(vals_dtype)))
+
+
+class TestHostAssembly:
+    """A batch whose query leaves are all host arrays is stacked on the
+    host and moved in one transfer per leaf; the result is what
+    ``jnp.stack`` gives, bit for bit and dtype for dtype."""
+
+    @staticmethod
+    def _batcher(pad, pad_tokens=None, batch_size=4):
+        return ContinuousBatcher("asm", lambda q, _t: q, pad, pad_tokens,
+                                 batch_size=batch_size, max_wait_s=0.005)
+
+    @staticmethod
+    def _requests(queries, tokens=None):
+        tokens = tokens if tokens is not None else [None] * len(queries)
+        return [Request(q, t, "asm", Future(), 0.0)
+                for q, t in zip(queries, tokens)]
+
+    @staticmethod
+    def _device_stacked(trees):
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+
+    @staticmethod
+    def _assert_same(got, want):
+        got_leaves, want_leaves = jax.tree.leaves(got), jax.tree.leaves(want)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for g, w in zip(got_leaves, want_leaves):
+            assert isinstance(g, jax.Array)
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert g.committed == w.committed
+            assert np.array_equal(np.asarray(g), np.asarray(w))
+
+    @pytest.mark.parametrize("fill", [1, 3, 4])
+    @pytest.mark.parametrize("kind", ["dense", "fused", "tokens"])
+    def test_host_stacked_bitwise_equal_to_jnp_stack(self, kind, fill):
+        rng = np.random.default_rng(fill)
+        tokens = pad_tokens = None
+        if kind == "fused":
+            pad = FusedVectors(np.zeros(8, np.float32), SparseVectors(
+                np.full(4, 50, np.int32), np.zeros(4, np.float32)))
+            queries = [_fused_query(rng) for _ in range(fill)]
+        else:
+            pad = np.zeros(8, np.float32)
+            queries = [rng.standard_normal(8).astype(np.float32)
+                       for _ in range(fill)]
+        if kind == "tokens":
+            pad_tokens = np.zeros(6, np.int32)
+            tokens = [rng.integers(0, 99, 6).astype(np.int32)
+                      for _ in range(fill)]
+        b = self._batcher(pad, pad_tokens)
+        try:
+            stacked, toks = b._assemble(self._requests(queries, tokens))
+        finally:
+            b.close()
+        assert b._stacked_on == "host"
+        self._assert_same(stacked,
+                          self._device_stacked(queries + [pad] * (4 - fill)))
+        if kind == "tokens":
+            self._assert_same(toks, self._device_stacked(
+                tokens + [pad_tokens] * (4 - fill)))
+        else:
+            assert toks is None
+
+    def test_dtypes_canonicalised_as_jnp_stack(self):
+        """int64 ids and float64 values (x64 off) come out int32 and
+        float32, as the device path gives them."""
+        rng = np.random.default_rng(5)
+        pad = FusedVectors(np.zeros(8), SparseVectors(
+            np.full(4, 50, np.int64), np.zeros(4, np.float64)))
+        queries = [_fused_query(rng, np.int64, np.float64) for _ in range(2)]
+        b = self._batcher(pad)
+        try:
+            stacked, _ = b._assemble(self._requests(queries))
+        finally:
+            b.close()
+        want = self._device_stacked(queries + [pad] * 2)
+        self._assert_same(stacked, want)
+        assert stacked.dense.dtype == jnp.float32
+        assert stacked.sparse.indices.dtype == jnp.int32
+        assert stacked.sparse.values.dtype == jnp.float32
+
+    def test_device_request_keeps_the_device_path(self):
+        """One query leaf held on a device sends that leaf through
+        ``jnp.stack``; the batch is not counted as host-assembled."""
+        rng = np.random.default_rng(7)
+        pad = FusedVectors(np.zeros(8, np.float32), SparseVectors(
+            np.full(4, 50, np.int32), np.zeros(4, np.float32)))
+        queries = [_fused_query(rng) for _ in range(4)]
+        queries[2] = FusedVectors(jnp.asarray(queries[2].dense),
+                                  queries[2].sparse)
+        b = self._batcher(pad)
+        try:
+            stacked, _ = b._assemble(self._requests(queries))
+        finally:
+            b.close()
+        assert b._stacked_on == "mixed"
+        self._assert_same(stacked, self._device_stacked(queries))
+
+        svc = RetrievalService(cache_size=0)
+        svc.register_runner("mixed", lambda q, _t: q.dense, pad,
+                            batch_size=4, max_wait_s=5.0)
+        with svc:
+            out = svc.retrieve(queries, endpoint="mixed")
+            ep = svc.snapshot().endpoints["mixed"]
+        assert ep.n_batches == 1 and ep.host_assembled_total == 0
+        for q, o in zip(queries, out):
+            assert np.array_equal(o, np.asarray(q.dense))
+
+    @pytest.mark.parametrize("kind", ["dense", "fused"])
+    def test_counter_counts_every_host_batch(self, kind):
+        rng = np.random.default_rng(11)
+        if kind == "fused":
+            pad = FusedVectors(np.zeros(8, np.float32), SparseVectors(
+                np.full(4, 50, np.int32), np.zeros(4, np.float32)))
+            queries = [_fused_query(rng) for _ in range(10)]
+            run = lambda q, _t: q.dense + q.sparse.values.sum(-1)[:, None]
+        else:
+            pad = np.zeros(8, np.float32)
+            queries = [rng.standard_normal(8).astype(np.float32)
+                       for _ in range(10)]
+            run = lambda q, _t: q * 2.0
+        svc = RetrievalService(cache_size=0)
+        svc.register_runner("host", run, pad, batch_size=4,
+                            max_wait_s=0.005)
+        with svc:
+            svc.retrieve(queries, endpoint="host")
+            ep = svc.snapshot().endpoints["host"]
+        assert ep.n_batches >= 3
+        assert ep.host_assembled_total == ep.n_batches
+
+    def test_counter_zero_on_a_device_endpoint(self, dense_setup):
+        pipe, q = dense_setup
+        with _service(pipe, q, cache_size=0, max_wait_s=0.005) as svc:
+            svc.retrieve([q[i] for i in range(5)], endpoint="dense")
+            ep = svc.snapshot().endpoints["dense"]
+        assert ep.n_batches >= 1 and ep.host_assembled_total == 0
